@@ -75,10 +75,12 @@ def make_dsl(n_routes: int) -> str:
 
 def _row(rows, name, us, *, qps, kernel, n_routes, d, precision,
          devices, traffic):
+    import jax
     rows.append({"name": name, "us_per_call": us, "qps": qps,
                  "kernel": kernel, "n_routes": n_routes, "d": d,
                  "precision": precision, "devices": devices,
-                 "traffic": traffic})
+                 "traffic": traffic,
+                 "platform": jax.devices()[0].platform})
 
 
 def bench_route_level(rows) -> list:
@@ -156,7 +158,7 @@ def _engine_core_qps(svc, b: int, d: int, *, reps: int = 3,
         run = lambda e: engine_mod._SIGNAL_EVAL(
             jnp.asarray(e), jnp.asarray(crisp), svc.engine.tensors,
             kernel_mode=svc.engine.kernel_mode,
-            interpret=svc.engine.interpret)
+            interpret=svc.engine.interpret, block_b=svc.engine.block_b)
     jax.block_until_ready(run(fresh())[2])        # compile + warm
     best = 0.0
     for _ in range(passes):
@@ -678,9 +680,12 @@ def sharded_worker() -> None:
 
 
 def bench_sharded_subprocess(rows) -> list:
-    """Spawn the 8-emulated-device worker and merge its rows."""
+    """Spawn the 8-emulated-device worker and merge its rows.  The
+    worker is a CPU emulation by design, pinned to the CPU: on a chip
+    host this process already holds the chip."""
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
         PYTHONPATH=str(ROOT / "src") + os.pathsep
         + os.environ.get("PYTHONPATH", ""))

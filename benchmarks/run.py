@@ -58,6 +58,8 @@ def main() -> None:
                             bench_router, bench_running_example,
                             bench_signal_pipeline, bench_table1,
                             bench_taxonomy)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     suites = [
         ("table1", bench_table1.main),
         ("running_example", bench_running_example.main),

@@ -188,10 +188,9 @@ def sharded_route_policy(mesh: Mesh, n_rules: int,
     the mesh's data-axes multiple (the router's bucket logic does
     this).  Decision- and score-bitwise-equal to the replicated
     ``evaluate_policy`` over the sharded signal eval."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.signals.engine import (_mesh_batch_axes,
-                                      _sharded_route_body)
+                                      _sharded_route_body, auto_axes)
+    mesh = auto_axes(mesh)
     daxes = _mesh_batch_axes(mesh)
     maxis = "model" if "model" in mesh.shape else None
     n_model = mesh.shape.get("model", 1)
@@ -218,9 +217,9 @@ def sharded_route_policy(mesh: Mesh, n_rules: int,
                 "neg_crisp": P(maxis, None), "need": P(maxis),
                 "tier_t": P(maxis), "pri_t": P(maxis),
                 "rule_t": P(maxis)}
-    sh = shard_map(body, mesh=mesh,
-                   in_specs=(bspec, bspec, st_specs, pt_specs),
-                   out_specs=(vspec, vspec), check_rep=False)
+    sh = jax.shard_map(body, mesh=mesh,
+                       in_specs=(bspec, bspec, st_specs, pt_specs),
+                       out_specs=(vspec, vspec), check_vma=False)
 
     @jax.jit
     def fn(emb, crisp_raw, st, pt):

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import voronoi as _vor
-from repro.kernels.voronoi import _NEG, _route_tail, unpack_int4
+from repro.kernels.voronoi import _HI, _NEG, _route_tail, unpack_int4
 
 
 def _dequant_rows(rows: jnp.ndarray, d: int) -> jnp.ndarray:
@@ -56,7 +56,7 @@ def flat_route(x, centroids, classifier_mask, col_scale, col_thr,
             else jnp.zeros((1, n), f32))
     deq = _dequant_rows(jnp.asarray(centroids), x.shape[1])
     sims = jax.lax.dot_general(x, deq, (((1,), (1,)), ((), ())),
-                               preferred_element_type=f32)
+                               precision=_HI, preferred_element_type=f32)
     if qscale is not None:
         sims = sims * jnp.asarray(qscale, f32).reshape(1, n)
     raw, scores, fired, win, wscore = _route_tail(
@@ -131,7 +131,7 @@ def _ivf_route_jnp(x, classifier_mask, col_scale, col_thr, grouped_mask,
 
     # stage 1: coarse Voronoi — top-nprobe slab heads per query
     hs = jax.lax.dot_general(x, jnp.asarray(heads, f32),
-                             (((1,), (1,)), ((), ())),
+                             (((1,), (1,)), ((), ())), precision=_HI,
                              preferred_element_type=f32)      # (B, S)
     _, pidx = jax.lax.top_k(hs, nprobe)                       # (B, np)
 
@@ -143,7 +143,7 @@ def _ivf_route_jnp(x, classifier_mask, col_scale, col_thr, grouped_mask,
 
     def _probe(_, pcol):
         slab = _dequant_rows(store3[pcol], d)                 # (B, k, D)
-        sims = jnp.einsum("bkd,bd->bk", slab, x,
+        sims = jnp.einsum("bkd,bd->bk", slab, x, precision=_HI,
                           preferred_element_type=f32)
         return None, sims * qs3[pcol]
 

@@ -62,8 +62,10 @@ def grouped_voronoi(sims, inv_tau, member, *, interpret=None,
 # fused routing: resident vs D-tiled variant selection
 # ---------------------------------------------------------------------------
 
-# per-core VMEM on current TPUs is ~16 MB; leave headroom for Mosaic's
-# own buffers, the metadata rows, and double-buffered pipelining
+# Mosaic's default scoped-VMEM limit on a v5e core is 16 MiB; the
+# estimates below are calibrated against what it actually allocates
+# (tests/test_chip_compile.py compiles the edge shapes), and the budget
+# keeps a quarter of the limit spare for Mosaic's internal scratch
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
 
 # route tables at or past this size auto-upgrade to the two-stage IVF
@@ -81,40 +83,53 @@ def precision_centroid_bytes(precision: str) -> float:
     return {"f32": 4.0, "bf16": 2.0, "int8": 1.0, "int4": 0.5}[precision]
 
 
+def _sublanes(rows: int) -> int:
+    """Rows an f32 block occupies in VMEM: padded to the 8-row tile."""
+    return -(-max(rows, 1) // 8) * 8
+
+
+def _route_tail_vmem_bytes(n: int, block_b: int, g: int) -> int:
+    """VMEM the routing tail holds per column, as Mosaic allocates it
+    for ``kernels/voronoi._route_tail`` (measured by bisecting the
+    VMEM limit of compiles for a v5e): twelve metadata rows and (1, N)
+    temporaries, the (G, N) partition and default, and eight (bb, N)
+    f32 buffers — the three outputs, the similarity accumulator, and
+    the softmax and per-group temporaries.  The per-group loop keeps
+    this flat in G."""
+    return n * 4 * (8 * 12 + 2 * _sublanes(g) + 8 * _sublanes(block_b))
+
+
 def fused_route_vmem_bytes(n: int, d: int, g: int = 1, *,
                            block_b: int = 128, block_n: int = 128,
                            centroid_bytes: float = 4) -> int:
-    """Resident-VMEM estimate for one grid step of the fully-resident
-    ``fused_route`` kernel: the whole (Npad, D) centroid store *at its
-    quantized width*, the f32 dequantization tile (the kernel casts one
-    (block_n, D) slice per fori_loop step), one (bb, D) query block,
-    the (bb, Npad) similarity/score buffers, and the column metadata."""
+    """VMEM estimate for one grid step of the fully-resident
+    ``fused_route`` kernel: the whole (Npad, D) centroid store at its
+    stored width (one buffer: Mosaic does not double-buffer a block
+    whose index never changes), one f32
+    dequantization tile for a quantized store, the double-buffered
+    (bb, D) query block, and the routing tail."""
     bn = max(1, min(block_n, max(n, 1)))
     npad = n + ((-n) % bn)
-    gp = max(g, 1)
-    return int(npad * d * centroid_bytes         # resident quantized store
-               + min(bn, npad) * d * 4           # per-tile f32 dequant
-               + block_b * d * 4                 # query block
-               + 4 * block_b * npad * 4          # sims acc + raw/scores/fired
-               + 2 * block_b * gp * 4            # winners
-               + (5 + 2 * gp) * npad * 4)        # metadata rows + partition
+    tile = bn * d * 4 if centroid_bytes < 4 else 0
+    return int(npad * d * centroid_bytes
+               + tile
+               + 2 * block_b * d * 4
+               + _route_tail_vmem_bytes(npad, block_b, g))
 
 
 def fused_route_dtiled_vmem_bytes(n: int, d: int, g: int = 1, *,
                                   block_b: int = 128, block_d: int = 256,
                                   centroid_bytes: float = 4) -> int:
-    """Resident-VMEM estimate for one grid step of the D-tiled variant:
-    an (N, block_d) centroid slab (plus its f32 cast when the store is
-    quantized) + the (bb, N) accumulator."""
+    """VMEM estimate for one grid step of the D-tiled variant: the
+    double-buffered streamed (N, block_d) centroid slab (plus its f32
+    cast when the store is quantized), the double-buffered query slab,
+    and the routing tail over all N columns."""
     bd = max(1, min(block_d, max(d, 1)))
-    gp = max(g, 1)
     cast = n * bd * 4 if centroid_bytes < 4 else 0
-    return int(n * bd * centroid_bytes           # streamed centroid slab
-               + cast                            # f32 cast of the slab
-               + block_b * bd * 4                # query slab
-               + 4 * block_b * n * 4             # scratch acc + outputs
-               + 2 * block_b * gp * 4
-               + (5 + 2 * gp) * n * 4)
+    return int(2 * n * bd * centroid_bytes
+               + cast
+               + 2 * block_b * bd * 4
+               + _route_tail_vmem_bytes(n, block_b, g))
 
 
 def select_fused_variant(n: int, d: int, g: int = 1, *,
@@ -150,6 +165,35 @@ def select_fused_variant(n: int, d: int, g: int = 1, *,
         n, d, g, block_b=block_b, block_d=block_d,
         centroid_bytes=centroid_bytes)
     return "fused_dtiled" if dtiled <= budget else "jnp"
+
+
+# query-row blocks the flat fused kernels may tile a batch by, widest
+# first.  The routing tail holds eight (bb, N) buffers, so a table too
+# wide to stay resident at 128 rows can still fit at 16 — the block
+# serving batches (power-of-two buckets of a few rows) run at anyway,
+# since a kernel blocks a B-row batch by min(bb, B).
+ROW_BLOCKS = (128, 64, 32, 16, 8)
+
+
+def select_fused_plan(n: int, d: int, g: int = 1, *,
+                      block_n: int = 128, block_d: int = 256,
+                      centroid_bytes: float = 4,
+                      budget_bytes: int | None = None) -> tuple[str, int]:
+    """-> ``(variant, block_b)``: the fully-resident kernel at the
+    widest ``ROW_BLOCKS`` block whose estimate fits the budget; failing
+    that, the D-tiled variant at the widest block it fits; failing
+    both, ``("jnp", 128)``.  A narrower block only costs batches wider
+    than it more grid steps over the same resident store."""
+    picks = [(select_fused_variant(n, d, g, block_b=bb, block_n=block_n,
+                                   block_d=block_d,
+                                   centroid_bytes=centroid_bytes,
+                                   budget_bytes=budget_bytes), bb)
+             for bb in ROW_BLOCKS]
+    for want in ("fused", "fused_dtiled"):
+        for variant, bb in picks:
+            if variant == want:
+                return variant, bb
+    return "jnp", ROW_BLOCKS[0]
 
 
 def select_route_variant(n: int, d: int, g: int = 1, *,

@@ -10,11 +10,12 @@ Three entry points:
   similarities for a single group.
 * ``fused_route`` — the whole signal layer in one launch: the
   (B, D)·(D, N) similarity GEMM against the stacked centroid matrix
-  (centroids resident in VMEM, tiled over N through a fori_loop
-  accumulator so centroid counts beyond one VMEM block stream through
-  MXU-sized tiles), classifier calibration, the segment-masked grouped
-  softmax, per-column thresholds with per-group default fallback, and
-  per-group winner indices + scores — five outputs, one kernel.
+  (centroids resident in VMEM, tiled over N through a fori_loop that
+  writes each MXU-sized tile into a VMEM scratch, so centroid counts
+  beyond one tile never issue one huge dot), classifier calibration,
+  the segment-masked grouped softmax, per-column thresholds with
+  per-group default fallback, and per-group winner indices + scores —
+  five outputs, one kernel.
 * ``fused_route_dtiled`` — the same contract for embedder dims too
   large to keep the whole (N, D) centroid matrix VMEM-resident: the
   grid gains a second (D-chunk) dimension, each step streams one
@@ -36,15 +37,25 @@ the GEMM accumulates in f32 (see signals/engine quantization).
   simultaneously.  Contract: membership is a partition (each column in
   exactly one group row, groups may be uneven/singleton); per-column
   scales are constant within a group; output column j is the softmax of
-  group(j) restricted to its member columns.  Per-group maxima use a
-  fori_loop over the static G rows; broadcasts and denominators are
+  group(j) restricted to its member columns.  Per-group maxima come
+  from a loop over the G group rows; broadcasts and denominators are
   one-hot matmuls on the MXU.  This replaces one kernel launch per
   SIGNAL_GROUP with exactly one launch per batch.
 
 All kernels tile queries over VMEM blocks of ``block_b`` rows
 (MXU-aligned 128) and keep the small operands (centroids, scales,
 membership) resident in VMEM across the grid.  Validated on CPU with
-``interpret=True`` against kernels/ref.py.
+``interpret=True`` against kernels/ref.py, and compiled for a described
+v5e chip by tests/test_chip_compile.py.
+
+Mosaic (the TPU kernel compiler) lowers no ``dynamic_slice`` or
+``dynamic_update_slice`` on values.  So the group loop picks row g by a
+one-hot contraction and writes column g by a lane-iota select
+(``_per_group``), and the N-tile loop writes each tile into a VMEM
+scratch at a ``pl.multiple_of``-aligned offset.  Every one-hot and
+similarity contraction runs at ``HIGHEST`` precision: on the TPU the
+default f32 matmul rounds its operands to bf16, which would move scores
+near a threshold differently in each lowering.
 """
 from __future__ import annotations
 
@@ -107,23 +118,49 @@ def voronoi_scores(x: jnp.ndarray, centroids: jnp.ndarray,
 
 
 _NEG = -3e38                   # finite -inf stand-in: 0 * _NEG == 0, not nan
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b, dims):
+    """f32 contraction at full precision (see the module docstring)."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _per_group(m: jnp.ndarray, fn, init):
+    """Loop over the G membership rows of ``m`` (G, N): ``fn(row)``
+    maps row g (1, N) to (bb, 1) results (one or a tuple), which land
+    in column g of the matching (bb, G) carries in ``init``.  Row g is
+    picked by a one-hot contraction and column g written by a lane-iota
+    select — Mosaic lowers neither a dynamic row slice nor a dynamic
+    column update of a value — and the loop stays a loop, so the VMEM
+    of the (bb, N) temporaries does not grow with G."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, m.shape[0]), 1)
+
+    def body(g, acc):
+        hit = lane == g
+        row = _dot(hit.astype(jnp.float32), m, ((1,), (0,)))  # (1, N)
+        return jax.tree.map(lambda a, v: jnp.where(hit, v, a), acc,
+                            fn(row))
+
+    return jax.lax.fori_loop(0, m.shape[0], body, init)
 
 
 def unpack_int4(packed: jnp.ndarray, d: int) -> jnp.ndarray:
-    """(N, P) uint8 packed int4 pairs -> (N, d) f32 in [-8, 7].
+    """(N, P) uint8 packed int4 -> (N, d) f32 in [-8, 7].
 
-    Column 2j lives in the low nibble of byte j, column 2j+1 in the
-    high nibble, two's-complement (signals/ivf.pack_int4 is the
-    inverse).  Nibble ops are VPU-elementwise, so in-kernel unpack adds
-    no MXU work — the store stays half an int8 in VMEM/HBM.
+    Column j lives in the low nibble of byte j, column P+j in the high
+    nibble, two's-complement (signals/ivf.pack_int4 is the inverse).
+    Nibble ops are VPU-elementwise and the halves join by one lane
+    concatenation, so in-kernel unpack adds no MXU work — the store
+    stays half an int8 in VMEM/HBM.
     """
     p = packed.astype(jnp.int32)
     lo = p & 0xF
     lo = lo - jnp.where(lo > 7, 16, 0)
     hi = (p >> 4) & 0xF
     hi = hi - jnp.where(hi > 7, 16, 0)
-    out = jnp.stack([lo, hi], axis=-1).reshape(p.shape[0], -1)
-    return out[:, :d].astype(jnp.float32)
+    return jnp.concatenate([lo, hi], axis=1)[:, :d].astype(jnp.float32)
 
 
 def _dequant_tile(cj: jnp.ndarray, unpack_d: int) -> jnp.ndarray:
@@ -137,6 +174,27 @@ def _dequant_tile(cj: jnp.ndarray, unpack_d: int) -> jnp.ndarray:
     return cj.astype(jnp.float32)
 
 
+def _tiled_sims(x, c_ref, acc_ref, block_n: int, unpack_d: int):
+    """x (bb, D) · dequant(store (Npad, Ds))ᵀ -> (bb, Npad) f32, one
+    (block_n, Ds) tile at a time: each fori_loop step slices the store
+    in its OWN dtype, dequantizes that tile only (a bf16/int8/int4
+    store never materializes as f32), and writes the tile's sims into
+    the VMEM scratch ``acc_ref`` at a block_n-aligned lane offset.  A
+    single tile skips the scratch."""
+    n_tiles = c_ref.shape[0] // block_n
+    if n_tiles == 1:
+        return _dot(x, _dequant_tile(c_ref[...], unpack_d), ((1,), (1,)))
+
+    def _tile(j, carry):
+        off = pl.multiple_of(j * block_n, block_n)
+        cj = _dequant_tile(c_ref[pl.ds(off, block_n), :], unpack_d)
+        acc_ref[:, pl.ds(off, block_n)] = _dot(x, cj, ((1,), (1,)))
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, _tile, 0)
+    return acc_ref[...]
+
+
 def _softmax_by_group(z: jnp.ndarray, m: jnp.ndarray, *,
                       reduce_max=None, reduce_sum=None) -> jnp.ndarray:
     """Segment-masked, numerically stable softmax over every group at
@@ -147,8 +205,8 @@ def _softmax_by_group(z: jnp.ndarray, m: jnp.ndarray, *,
     guarded value the caller must mask out).  -> (bb, N) where member
     column j holds the softmax of group(j) restricted to its columns.
 
-    The per-group max is computed with a fori_loop over the (static) G
-    group rows; the max/denominator broadcast back to columns and the
+    The per-group max is a loop of masked row reductions over the G
+    groups; the max/denominator broadcast back to columns and the
     per-group sum both ride the MXU as one-hot matmuls, so the whole
     batch needs exactly one kernel launch regardless of group count.
 
@@ -158,30 +216,18 @@ def _softmax_by_group(z: jnp.ndarray, m: jnp.ndarray, *,
     shards (pmax/psum) between the local reductions and the broadcast
     back to columns.  None (the kernel case) means single-shard.
     """
-    f32 = jnp.float32
-    n_groups = m.shape[0]
     covered = jnp.sum(m, axis=0, keepdims=True) > 0.0         # (1, N)
-
-    def _gmax(g, acc):
-        row = jax.lax.dynamic_slice_in_dim(m, g, 1, axis=0)   # (1, N)
-        zg = jnp.where(row > 0.0, z, _NEG)
-        mg = jnp.max(zg, axis=-1, keepdims=True)              # (bb, 1)
-        return jax.lax.dynamic_update_slice_in_dim(acc, mg, g, axis=1)
-
-    gmax = jax.lax.fori_loop(
-        0, n_groups, _gmax,
-        jnp.full((z.shape[0], n_groups), _NEG, f32))          # (bb, G)
+    gmax = _per_group(m, lambda row: jnp.max(                 # (bb, G)
+        jnp.where(row > 0.0, z, _NEG), axis=-1, keepdims=True),
+        jnp.full((z.shape[0], m.shape[0]), _NEG, jnp.float32))
     if reduce_max is not None:
         gmax = reduce_max(gmax)
-    col_max = jax.lax.dot_general(                            # (bb, N)
-        gmax, m, (((1,), (0,)), ((), ())), preferred_element_type=f32)
+    col_max = _dot(gmax, m, ((1,), (0,)))                     # (bb, N)
     e = jnp.exp(jnp.where(covered, z - col_max, 0.0))         # ≤ 1 covered
-    gsum = jax.lax.dot_general(                               # (bb, G)
-        e, m, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+    gsum = _dot(e, m, ((1,), (1,)))                           # (bb, G)
     if reduce_sum is not None:
         gsum = reduce_sum(gsum)
-    denom = jax.lax.dot_general(                              # (bb, N) ≥ 1
-        gsum, m, (((1,), (0,)), ((), ())), preferred_element_type=f32)
+    denom = _dot(gsum, m, ((1,), (0,)))                       # (bb, N) ≥ 1
     return e / jnp.maximum(denom, 1e-30)     # guard: uncovered denom == 0
 
 
@@ -252,7 +298,6 @@ def _route_tail(sims, cls, scale, thr, grouped_row, member, default, *,
     raw = jnp.where(cls > 0.0, (sims + 1.0) * 0.5, sims)
     z = sims * scale
     m = member.astype(f32)                                    # (G, Np)
-    n_groups = m.shape[0]
     scores = jnp.where(
         grouped,
         _softmax_by_group(z, m, reduce_max=reduce_max,
@@ -260,36 +305,27 @@ def _route_tail(sims, cls, scale, thr, grouped_row, member, default, *,
         raw)
 
     # grouped columns threshold strictly at the group θ; ungrouped use
-    # the signal's own inclusive threshold (engine semantics, Def 1)
-    fired = jnp.where(grouped, scores > thr, raw >= thr)
-    group_any = jax.lax.dot_general(                          # (bb, G)
-        fired.astype(f32), m, (((1,), (1,)), ((), ())),
-        preferred_element_type=f32)
+    # the signal's own inclusive threshold (engine semantics, Def 1);
+    # a select between two bool vectors does not lower in Mosaic
+    fired = (grouped & (scores > thr)) | (~grouped & (raw >= thr))
+    group_any = _dot(fired.astype(f32), m, ((1,), (1,)))     # (bb, G)
     if reduce_sum is not None:
         group_any = reduce_sum(group_any)
     group_any = group_any > 0.0
-    fallback = jax.lax.dot_general(                           # (bb, Np)
-        (~group_any).astype(f32), default,
-        (((1,), (0,)), ((), ())), preferred_element_type=f32) > 0.0
+    fallback = _dot((~group_any).astype(f32), default,        # (bb, Np)
+                    ((1,), (0,))) > 0.0
     fired = fired | fallback
 
-    def _win(g, carry):
-        win, wsc = carry
-        row = jax.lax.dynamic_slice_in_dim(m, g, 1, axis=0)   # (1, Np)
+    def _winner(row):
         sg = jnp.where(row > 0.0, scores, -1.0)               # scores ≥ 0
-        idx = (jnp.argmax(sg, axis=-1).astype(jnp.int32)
-               + jnp.asarray(col_offset, jnp.int32))          # (bb,)
-        best = jnp.max(sg, axis=-1)
-        win = jax.lax.dynamic_update_slice_in_dim(
-            win, idx[:, None], g, axis=1)
-        wsc = jax.lax.dynamic_update_slice_in_dim(
-            wsc, best[:, None], g, axis=1)
-        return win, wsc
+        return (jnp.argmax(sg, axis=-1, keepdims=True).astype(jnp.int32),
+                jnp.max(sg, axis=-1, keepdims=True))
 
-    win, wscore = jax.lax.fori_loop(
-        0, n_groups, _win,
-        (jnp.zeros((z.shape[0], n_groups), jnp.int32),
-         jnp.full((z.shape[0], n_groups), -1.0, f32)))
+    bb, n_groups = scores.shape[0], m.shape[0]
+    win, wscore = _per_group(m, _winner, (
+        jnp.zeros((bb, n_groups), jnp.int32),
+        jnp.full((bb, n_groups), -1.0, f32)))
+    win = win + jnp.asarray(col_offset, jnp.int32)            # (bb, G)
     if reduce_max is not None:
         best = reduce_max(wscore)                             # (bb, G)
         win = reduce_min(jnp.where(wscore >= best, win,
@@ -302,7 +338,8 @@ def _route_tail(sims, cls, scale, thr, grouped_row, member, default, *,
 def _fused_route_kernel(x_ref, c_ref, qscale_ref, cls_ref, scale_ref,
                         thr_ref, grouped_ref, member_ref, default_ref,
                         raw_ref, scores_ref, fired_ref, win_ref,
-                        wscore_ref, *, block_n: int, unpack_d: int = 0):
+                        wscore_ref, acc_ref, *, block_n: int,
+                        unpack_d: int = 0):
     """The whole signal layer for one query block, single launch.
 
     x_ref:       (bb, D)   unit query embeddings
@@ -321,29 +358,12 @@ def _fused_route_kernel(x_ref, c_ref, qscale_ref, cls_ref, scale_ref,
 
     Emits raw calibrated scores, grouped-normalized scores, fired mask
     (thresholds + default fallback), and the per-group winner column +
-    winning score.  The similarity GEMM runs tiled over N: each
-    fori_loop step dots the query block against one (block_n, D) slice
-    of the resident centroids and accumulates into the (bb, Np) buffer,
-    so N beyond one MXU tile streams instead of issuing one huge dot.
+    winning score.  The similarity GEMM runs tiled over N
+    (``_tiled_sims``, scratch ``acc_ref`` (bb, Np)), so N beyond one MXU
+    tile streams instead of issuing one huge dot.
     """
-    f32 = jnp.float32
-    x = x_ref[...].astype(f32)                                # (bb, D)
-    npad = c_ref.shape[0]
-    n_tiles = npad // block_n
-
-    def _tile(j, acc):
-        # slice the store in its OWN dtype, dequantize one tile at a
-        # time — a bf16/int8/int4 store must not materialize as f32
-        cj = _dequant_tile(c_ref[pl.ds(j * block_n, block_n), :],
-                           unpack_d)
-        sims_j = jax.lax.dot_general(
-            x, cj, (((1,), (1,)), ((), ())),
-            preferred_element_type=f32)                       # (bb, bn)
-        return jax.lax.dynamic_update_slice_in_dim(
-            acc, sims_j, j * block_n, axis=1)
-
-    sims = jax.lax.fori_loop(
-        0, n_tiles, _tile, jnp.zeros((x.shape[0], npad), f32))
+    x = x_ref[...].astype(jnp.float32)                        # (bb, D)
+    sims = _tiled_sims(x, c_ref, acc_ref, block_n, unpack_d)
     sims = sims * qscale_ref[...]
 
     raw, scores, fired, win, wscore = _route_tail(
@@ -382,8 +402,7 @@ def _fused_route_dtiled_kernel(x_ref, c_ref, qscale_ref, cls_ref,
 
     x = x_ref[...].astype(f32)                                # (bb, bd)
     c = c_ref[...].astype(f32)                                # (N, bd)
-    acc_ref[...] += jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+    acc_ref[...] += _dot(x, c, ((1,), (1,)))
 
     @pl.when(j == n_dtiles - 1)
     def _finish():
@@ -483,6 +502,7 @@ def fused_route(x: jnp.ndarray, centroids: jnp.ndarray,
             jax.ShapeDtypeStruct((x.shape[0], gp), jnp.int32),
             jax.ShapeDtypeStruct((x.shape[0], gp), f32),
         ],
+        scratch_shapes=[pltpu.VMEM((bb, npad), f32)],
         interpret=interpret,
     )(x, cmat, qs, cls, scale, thr, grp, memberp, defaultp)
     return (raw[:b, :n], scores[:b, :n], fired[:b, :n] > 0.5,
@@ -579,27 +599,13 @@ def fused_route_dtiled(x: jnp.ndarray, centroids: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _fused_sims_kernel(x_ref, c_ref, qs_ref, o_ref, *, block_n: int,
-                       unpack_d: int = 0):
+def _fused_sims_kernel(x_ref, c_ref, qs_ref, o_ref, acc_ref, *,
+                       block_n: int, unpack_d: int = 0):
     """x (bb, D) · store (Npad, Ds)ᵀ -> dequantized sims (bb, Npad).
-    Same N-tiled accumulation and per-tile dequantization as
-    ``_fused_route_kernel``, without the routing tail."""
-    f32 = jnp.float32
-    x = x_ref[...].astype(f32)
-    npad = c_ref.shape[0]
-    n_tiles = npad // block_n
-
-    def _tile(j, acc):
-        cj = _dequant_tile(c_ref[pl.ds(j * block_n, block_n), :],
-                           unpack_d)
-        sims_j = jax.lax.dot_general(
-            x, cj, (((1,), (1,)), ((), ())),
-            preferred_element_type=f32)
-        return jax.lax.dynamic_update_slice_in_dim(
-            acc, sims_j, j * block_n, axis=1)
-
-    sims = jax.lax.fori_loop(
-        0, n_tiles, _tile, jnp.zeros((x.shape[0], npad), f32))
+    Same N-tiled GEMM (``_tiled_sims``) as ``_fused_route_kernel``,
+    without the routing tail."""
+    x = x_ref[...].astype(jnp.float32)
+    sims = _tiled_sims(x, c_ref, acc_ref, block_n, unpack_d)
     o_ref[...] = sims * qs_ref[...]
 
 
@@ -638,6 +644,7 @@ def fused_sims(x: jnp.ndarray, centroids: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bb, npad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], npad), f32),
+        scratch_shapes=[pltpu.VMEM((bb, npad), f32)],
         interpret=interpret,
     )(x, cmat, qs)
     return out[:b, :n]
@@ -652,27 +659,26 @@ def fused_sims(x: jnp.ndarray, centroids: jnp.ndarray,
 def _coarse_topk_kernel(x_ref, h_ref, val_ref, idx_ref, *, nprobe: int):
     """Query×heads GEMM fused with iterative top-``nprobe`` selection
     (argmax + mask-out per step; first-occurrence tie-breaking matches
-    ``jax.lax.top_k``'s lower-index-first ordering)."""
+    ``jax.lax.top_k``'s lower-index-first ordering).  Step ``p`` lands
+    in output column ``p`` through a lane-iota select (Mosaic lowers no
+    dynamic_update_slice on values)."""
     f32 = jnp.float32
     x = x_ref[...].astype(f32)                                # (bb, D)
     h = h_ref[...].astype(f32)                                # (S, D)
-    sims = jax.lax.dot_general(
-        x, h, (((1,), (1,)), ((), ())),
-        preferred_element_type=f32)                           # (bb, S)
+    sims = _dot(x, h, ((1,), (1,)))                           # (bb, S)
     cols = jax.lax.broadcasted_iota(jnp.int32, sims.shape, 1)
+    bb = sims.shape[0]
+    pcol = jax.lax.broadcasted_iota(jnp.int32, (bb, nprobe), 1)
 
     def _probe(p, carry):
         cur, vals, idxs = carry
-        best = jnp.max(cur, axis=-1)                          # (bb,)
-        bi = jnp.argmax(cur, axis=-1).astype(jnp.int32)
-        vals = jax.lax.dynamic_update_slice_in_dim(
-            vals, best[:, None], p, axis=1)
-        idxs = jax.lax.dynamic_update_slice_in_dim(
-            idxs, bi[:, None], p, axis=1)
-        cur = jnp.where(cols == bi[:, None], _NEG, cur)
+        best = jnp.max(cur, axis=-1, keepdims=True)           # (bb, 1)
+        bi = jnp.argmax(cur, axis=-1, keepdims=True).astype(jnp.int32)
+        vals = jnp.where(pcol == p, best, vals)
+        idxs = jnp.where(pcol == p, bi, idxs)
+        cur = jnp.where(cols == bi, _NEG, cur)
         return cur, vals, idxs
 
-    bb = sims.shape[0]
     _, vals, idxs = jax.lax.fori_loop(
         0, nprobe, _probe,
         (sims, jnp.full((bb, nprobe), _NEG, f32),
@@ -728,58 +734,54 @@ def _ivf_route_kernel(pidx_ref, x_ref, c_ref, qs_ref, cls_ref, scale_ref,
     the body runs — the gather is pure DMA scheduling, no in-kernel
     indexing.  Each step dots the query row against one dequantized
     (slab_k, D) slab and stages the slab's sims + metadata into
-    candidate-space VMEM scratch at probe offset ``p·slab_k``; the
-    last probe runs the shared ``_route_tail`` over the (1, Kc)
-    candidate space and maps each group winner to the smallest
-    *original* column id attaining its best score (the flat kernel's
-    first-occurrence argmax, in global column order).  Only
-    ``nprobe·slab_k`` candidate columns ever occupy VMEM, which is
-    what keeps 100k+ route tables VMEM-resident per stage.
+    candidate-space VMEM scratch at probe offset ``p·slab_k`` (a lane
+    multiple: signals/ivf rounds slab widths to 128); the last probe
+    runs the shared ``_route_tail`` over the (1, Kc) candidate space
+    and maps each group winner to the smallest *original* column id
+    attaining its best score (the flat kernel's first-occurrence
+    argmax, in global column order).  Only ``nprobe·slab_k`` candidate
+    columns ever occupy VMEM, which is what keeps 100k+ route tables
+    VMEM-resident per stage.  Query and output blocks are one
+    (1, 1, ·) row of a (B, 1, ·) view, so their last two block dims
+    span the array.
     """
     f32 = jnp.float32
     p = pl.program_id(1)
-    x = x_ref[...].astype(f32)                                # (1, D)
+    x = x_ref[0].astype(f32)                                  # (1, D)
     slab = _dequant_tile(c_ref[0], unpack_d)                  # (slab_k, D)
-    sims_p = jax.lax.dot_general(
-        x, slab, (((1,), (1,)), ((), ())),
-        preferred_element_type=f32) * qs_ref[...]             # (1, slab_k)
-    off = p * slab_k
-    acc[:, pl.ds(off, slab_k)] = sims_p
-    cls_s[:, pl.ds(off, slab_k)] = cls_ref[...]
-    scale_s[:, pl.ds(off, slab_k)] = scale_ref[...]
-    thr_s[:, pl.ds(off, slab_k)] = thr_ref[...]
-    grp_s[:, pl.ds(off, slab_k)] = grp_ref[...]
-    mem_s[:, pl.ds(off, slab_k)] = member_ref[...]
-    dflt_s[:, pl.ds(off, slab_k)] = default_ref[...]
-    colid_s[:, pl.ds(off, slab_k)] = colid_ref[...]
+    sims_p = _dot(x, slab, ((1,), (1,))) * qs_ref[...]        # (1, slab_k)
+    span = pl.ds(pl.multiple_of(p * slab_k, slab_k), slab_k)
+    acc[:, span] = sims_p
+    cls_s[:, span] = cls_ref[...]
+    scale_s[:, span] = scale_ref[...]
+    thr_s[:, span] = thr_ref[...]
+    grp_s[:, span] = grp_ref[...]
+    mem_s[:, span] = member_ref[...]
+    dflt_s[:, span] = default_ref[...]
+    colid_s[:, span] = colid_ref[...]
 
     @pl.when(p == nprobe - 1)
     def _finish():
-        sims = acc[...]                                       # (1, Kc)
         raw, scores, fired, _, wscore = _route_tail(
-            sims, cls_s[...], scale_s[...], thr_s[...], grp_s[...],
+            acc[...], cls_s[...], scale_s[...], thr_s[...], grp_s[...],
             mem_s[...], dflt_s[...])
-        raw_ref[...] = raw
-        scores_ref[...] = scores
-        fired_ref[...] = fired.astype(f32)
+        raw_ref[0] = raw
+        scores_ref[0] = scores
+        fired_ref[0] = fired.astype(f32)
         colid = colid_s[...]                                  # (1, Kc)
-        m = mem_s[...]
-        n_groups = m.shape[0]
 
-        def _wmap(g, wacc):
-            row = jax.lax.dynamic_slice_in_dim(m, g, 1, axis=0)
+        def _wmap(row):
             sg = jnp.where(row > 0.0, scores, -1.0)
             best = jnp.max(sg, axis=-1, keepdims=True)        # (1, 1)
             cand = (row > 0.0) & (sg >= best)
-            wmin = jnp.min(jnp.where(cand, colid, 3e38), axis=-1)
-            wg = jnp.where(best[:, 0] < 0.0, 0.0, wmin)       # (1,)
-            return jax.lax.dynamic_update_slice_in_dim(
-                wacc, wg[:, None], g, axis=1)
+            wmin = jnp.min(jnp.where(cand, colid, 3e38), axis=-1,
+                           keepdims=True)
+            return jnp.where(best < 0.0, 0.0, wmin)
 
-        wmap = jax.lax.fori_loop(
-            0, n_groups, _wmap, jnp.zeros((1, n_groups), f32))
-        win_ref[...] = wmap.astype(jnp.int32)
-        wscore_ref[...] = wscore
+        m = mem_s[...]
+        win_ref[0] = _per_group(m, _wmap, jnp.zeros(
+            (1, m.shape[0]), f32)).astype(jnp.int32)
+        wscore_ref[0] = wscore
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -804,29 +806,20 @@ def ivf_route_candidates(x: jnp.ndarray, pidx: jnp.ndarray,
     gp = member_s.shape[0]
     f32 = jnp.float32
     packed = store3.dtype == jnp.uint8
+    row = lambda w: pl.BlockSpec((1, 1, w), lambda i, p, pr: (i, 0, 0))
+    slab = lambda h: pl.BlockSpec((h, slab_k),
+                                  lambda i, p, pr: (0, pr[i, p]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, nprobe),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, p, pr: (i, 0)),
+            row(d),
             pl.BlockSpec((1, slab_k, dstore),
                          lambda i, p, pr: (pr[i, p], 0, 0)),
-            pl.BlockSpec((1, slab_k), lambda i, p, pr: (0, pr[i, p])),
-            pl.BlockSpec((1, slab_k), lambda i, p, pr: (0, pr[i, p])),
-            pl.BlockSpec((1, slab_k), lambda i, p, pr: (0, pr[i, p])),
-            pl.BlockSpec((1, slab_k), lambda i, p, pr: (0, pr[i, p])),
-            pl.BlockSpec((1, slab_k), lambda i, p, pr: (0, pr[i, p])),
-            pl.BlockSpec((gp, slab_k), lambda i, p, pr: (0, pr[i, p])),
-            pl.BlockSpec((gp, slab_k), lambda i, p, pr: (0, pr[i, p])),
-            pl.BlockSpec((1, slab_k), lambda i, p, pr: (0, pr[i, p])),
+            slab(1), slab(1), slab(1), slab(1), slab(1),
+            slab(gp), slab(gp), slab(1),
         ],
-        out_specs=[
-            pl.BlockSpec((1, kc), lambda i, p, pr: (i, 0)),
-            pl.BlockSpec((1, kc), lambda i, p, pr: (i, 0)),
-            pl.BlockSpec((1, kc), lambda i, p, pr: (i, 0)),
-            pl.BlockSpec((1, gp), lambda i, p, pr: (i, 0)),
-            pl.BlockSpec((1, gp), lambda i, p, pr: (i, 0)),
-        ],
+        out_specs=[row(kc), row(kc), row(kc), row(gp), row(gp)],
         scratch_shapes=[
             pltpu.VMEM((1, kc), f32),                 # sims accumulator
             pltpu.VMEM((1, kc), f32),                 # cls
@@ -838,20 +831,22 @@ def ivf_route_candidates(x: jnp.ndarray, pidx: jnp.ndarray,
             pltpu.VMEM((1, kc), f32),                 # colid
         ],
     )
-    return pl.pallas_call(
+    raw, scores, fired, win, wscore = pl.pallas_call(
         functools.partial(_ivf_route_kernel, nprobe=nprobe,
                           slab_k=slab_k, unpack_d=d if packed else 0),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, kc), f32),
-            jax.ShapeDtypeStruct((b, kc), f32),
-            jax.ShapeDtypeStruct((b, kc), f32),
-            jax.ShapeDtypeStruct((b, gp), jnp.int32),
-            jax.ShapeDtypeStruct((b, gp), f32),
+            jax.ShapeDtypeStruct((b, 1, kc), f32),
+            jax.ShapeDtypeStruct((b, 1, kc), f32),
+            jax.ShapeDtypeStruct((b, 1, kc), f32),
+            jax.ShapeDtypeStruct((b, 1, gp), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, gp), f32),
         ],
         interpret=interpret,
-    )(pidx.astype(jnp.int32), x.astype(f32), store3, qscale_s,
-      cls_s, scale_s, thr_s, grp_s, member_s, default_s, colid_s)
+    )(pidx.astype(jnp.int32), x.astype(f32).reshape(b, 1, d), store3,
+      qscale_s, cls_s, scale_s, thr_s, grp_s, member_s, default_s,
+      colid_s)
+    return (raw[:, 0], scores[:, 0], fired[:, 0], win[:, 0], wscore[:, 0])
 
 
 def _softmax_kernel(s_ref, inv_tau_ref, o_ref):

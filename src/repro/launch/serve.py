@@ -44,6 +44,7 @@ import pathlib
 import threading
 import time
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.audit import AuditSink
 from repro.serving.faults import BreakerConfig, RetryPolicy
 from repro.serving.router import RouterService
@@ -210,6 +211,7 @@ def main(argv=None):
                     help="per-step diagnostics JSONL path for "
                          "--scenario replay")
     args = ap.parse_args(argv)
+    print(f"[serve] compile cache: {use_compile_cache()}")
     if args.scenario or args.ingress:
         args.continuous = True
     if args.prefill_chunk is not None and args.slots is None:
@@ -260,6 +262,9 @@ def main(argv=None):
                         prefill_chunk=args.prefill_chunk)
     for d in svc.diagnostics:
         print(f"[validate] {d}")
+    print(f"[serve] signal lowering {svc.engine.kernel_mode!r} "
+          f"(interpret={svc.engine.interpret}, "
+          f"fused_ok={svc.engine.fused_ok})")
     for spec in args.fault_rate:
         name, _, p = spec.rpartition(":")
         svc.faults.inject(name, error_rate=float(p))
@@ -280,6 +285,7 @@ def main(argv=None):
     # against scheduler slack computations under NTP adjustment)
     t0 = svc.cbatcher.clock()
     front = None
+    crashed = unserved = 0
     if args.ingress:
         from repro.serving.ingress import AsyncIngress, IngressConfig
         front = AsyncIngress(svc, IngressConfig(
@@ -316,6 +322,8 @@ def main(argv=None):
                 print(f"[serve] autoscale: {rep.autoscale}")
             if svc.scheduler is not None:
                 print(f"[serve] scheduler stats: {svc.scheduler.stats}")
+            if rep.crashed_steps:
+                raise SystemExit(1)
             return []
         if front is not None:
             front.start()
@@ -324,9 +332,19 @@ def main(argv=None):
                        for t in args.requests]
             for t in tickets:
                 t.wait(timeout=600.0)
-            print(f"[serve] ingress drain: {front.drain()}")
+            summary = front.drain()
+            crashed = summary["crashed_steps"]
+            print(f"[serve] ingress drain: {summary}")
             reqs = [t.request for t in tickets if t.request is not None]
             done = sum(t.status == "done" for t in tickets)
+            # a ticket may end short of done only as a flag allows; one
+            # cancelled by the drain (a wedged device step) never may
+            allowed = {"done"}
+            if args.timeout_s is not None:
+                allowed.add("timed_out")
+            if args.queue_cap is not None or args.brownout:
+                allowed.add("shed")
+            unserved = sum(t.status not in allowed for t in tickets)
             for t in tickets:
                 if t.status != "done":
                     print(f"[serve] {t.text[:48]!r} -> {t.status}"
@@ -366,6 +384,16 @@ def main(argv=None):
               + (f" -> {args.audit_log}" if args.audit_log else ""))
     print(f"[serve] {done} requests in {dt:.2f}s "
           f"({done*args.new_tokens/max(dt,1e-9):.1f} tok/s)")
+    # containment keeps serving through backend faults; a fault nobody
+    # injected (a kernel the chip refused, say) still fails the run
+    chaos = bool(args.fault_rate or args.kill_backend)
+    bad = [r for r in reqs if r.failed
+           or (not chaos and (r.fallback_used or r.retries))]
+    if bad or crashed or unserved:
+        print(f"[serve] {len(bad)} request(s) failed or needed fault "
+              f"containment without injected faults, {unserved} ticket(s) "
+              f"unserved, {crashed} crashed ingress steps")
+        raise SystemExit(1)
     return reqs
 
 

@@ -182,7 +182,6 @@ def _ep_moe(p, cfg: ModelConfig, x):
     (EXPERIMENTS.md §Perf H3-iter3).  Falls back to the local sort path
     when no mesh is active or experts don't divide the axis."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed import sharding as shd
 
     m = cfg.moe
@@ -218,11 +217,11 @@ def _ep_moe(p, cfg: ModelConfig, x):
         y = jax.lax.psum(y, "model")
         return y.reshape(nb, sb, d)
 
-    y = shard_map(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
-        out_specs=x_spec, check_rep=False,
+        out_specs=x_spec, check_vma=False,
     )(x, p["router"], p["e_gate"], p["e_up"], p["e_down"])
     return y
 

@@ -62,8 +62,9 @@ The fault-tolerant tier wraps all of the above:
   watches the live score stream for co-fire/against-evidence drift
   (``conflict_alerts()``).
 
-Backends are real JAX models (reduced configs on CPU; the full configs
-are exercised by launch/dryrun.py on the production mesh).
+Backends are real JAX models: the reduced same-family config by
+default, the published config when a BACKEND block says
+``size: "published"`` (docs/dsl.md).
 """
 from __future__ import annotations
 
@@ -96,9 +97,9 @@ from repro.signals.embedder import HashEmbedder
 
 @functools.partial(jax.jit,
                    static_argnames=("n_rules", "kernel_mode", "interpret",
-                                    "nprobe"))
+                                    "nprobe", "block_b"))
 def _route_core(emb, crisp_raw, tensors, jt, n_rules, kernel_mode,
-                interpret, nprobe=1):
+                interpret, nprobe=1, block_b=128):
     """embeddings + crisp scores -> (route index, score, normalized
     activations, fired mask): the whole signal pipeline and the policy
     argmax as one XLA program.  ``kernel_mode`` picks the signal
@@ -110,7 +111,7 @@ def _route_core(emb, crisp_raw, tensors, jt, n_rules, kernel_mode,
     program either way, and stay on device unless materialized)."""
     _, normalized, fired, conf = engine_mod._signal_eval_core(
         emb, crisp_raw, tensors, kernel_mode=kernel_mode,
-        interpret=interpret, nprobe=nprobe)
+        interpret=interpret, nprobe=nprobe, block_b=block_b)
     idx, score = policy_mod.evaluate_policy(jt, n_rules, fired, conf)
     return idx, score, normalized, fired
 
@@ -136,6 +137,18 @@ def _sharded_route_core(mesh, n_rules: int, body_kernel: str = "jnp",
         return idx, score, normalized, fired
 
     return fn
+
+
+BACKEND_SIZES = ("smoke", "published")
+
+
+@functools.lru_cache(maxsize=16)
+def _init_params(cfg):
+    """Jitted ``Model.init`` for one config: weights are drawn and cast
+    inside one program, so a bf16 backend never holds its float32 image
+    on the device, and services loading the same config share the
+    compile."""
+    return jax.jit(build_model(cfg).init)
 
 
 @dataclasses.dataclass
@@ -576,20 +589,28 @@ class RouterService:
     # ---- backends -------------------------------------------------------------
     def _load_backends(self, config: Optional[RouterConfig] = None):
         """Load every backend ``config`` declares that is not already
-        resident (rebind reuses loaded models across generations)."""
+        resident (rebind reuses loaded models across generations).
+
+        A BACKEND's ``size`` picks its widths: ``"smoke"`` (default) is
+        the reduced same-family variant, ``"published"`` the arch's own
+        config (configs/archs.py, bf16)."""
         config = config if config is not None else self.config
         for name, fields in config.backends.items():
             if name in self.backends:
                 continue
             arch = str(fields.get("arch", "internlm2-1.8b"))
-            cfg = get_config(arch, smoke=True)
+            size = str(fields.get("size", "smoke"))
+            if size not in BACKEND_SIZES:
+                raise ValueError(f"BACKEND {name}: size must be one of "
+                                 f"{BACKEND_SIZES}, got {size!r}")
+            cfg = get_config(arch, smoke=size == "smoke")
             model = build_model(cfg)
             max_seq = int(fields.get("max_seq", 128))
             # stable digest, NOT hash(): Python string hashing is salted
             # per process, so hash(name) weights differ across runs and
             # decode tokens are irreproducible
             seed = zlib.crc32(name.encode("utf-8")) & 0xFFFF
-            params = model.init(jax.random.PRNGKey(seed))
+            params = _init_params(cfg)(jax.random.PRNGKey(seed))
             self.backends[name] = BackendRuntime(
                 name=name, arch=arch, model=model, params=params,
                 decode=jax.jit(model.decode_step,
@@ -671,7 +692,8 @@ class RouterService:
                     jnp.asarray(emb), jnp.asarray(crisp), engine.tensors,
                     gen.jt, gen.tables.n_rules,
                     kernel_mode=engine.kernel_mode,
-                    interpret=engine.interpret, nprobe=engine.nprobe)
+                    interpret=engine.interpret, nprobe=engine.nprobe,
+                    block_b=engine.block_b)
             idx = np.asarray(idx)[:b]
             score = np.asarray(score)[:b]
             if observe:

@@ -44,9 +44,9 @@ Scale levers (all composable, README "Scaling the router"):
     (``sharded_fused_route``): batch over the mesh's (pod, data) axes,
     centroid columns over ``model``, with exact cross-device grouped
     softmax (pmax/psum) and first-occurrence winner reductions.
-  * VMEM auto-selection — a resolved ``fused`` upgrades itself to
-    ``fused_dtiled`` when the bound store exceeds the VMEM budget
-    (kernels/ops.select_fused_variant).
+  * VMEM auto-selection — a resolved ``fused`` narrows its query-row
+    block, then upgrades itself to ``fused_dtiled``, when the bound
+    store exceeds the VMEM budget (kernels/ops.select_fused_plan).
 """
 from __future__ import annotations
 
@@ -59,7 +59,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core.atoms import AtomKind
 from repro.dsl.compiler import RouterConfig
@@ -180,7 +180,8 @@ def _device_tables(np_tensors: Dict[str, np.ndarray], *,
 
 def _signal_eval_core(emb: jnp.ndarray, crisp_raw: jnp.ndarray,
                       t: Dict[str, jnp.ndarray], *,
-                      kernel_mode: str, interpret: bool, nprobe: int = 1
+                      kernel_mode: str, interpret: bool, nprobe: int = 1,
+                      block_b: int = 128
                       ) -> Tuple[jnp.ndarray, jnp.ndarray,
                                  jnp.ndarray, jnp.ndarray]:
     """embeddings + crisp scores -> (raw, normalized, fired, confidence).
@@ -194,7 +195,8 @@ def _signal_eval_core(emb: jnp.ndarray, crisp_raw: jnp.ndarray,
       default fallback all in ONE Pallas launch;
     * ``"fused_dtiled"`` — kernels/voronoi.fused_route_dtiled: the same
       single launch with the centroid store streamed through VMEM in
-      D-chunks (embedder dims past the VMEM budget);
+      D-chunks (embedder dims past the VMEM budget); both tile queries
+      by ``block_b`` rows (kernels/ops.select_fused_plan);
     * ``"ivf"`` / ``"ivf_fused"`` — the two-stage IVF path over the
       bind-time slab bundle (``ivf_*`` keys in ``t``): coarse
       top-``nprobe`` slab heads, then the routing tail over only the
@@ -227,7 +229,7 @@ def _signal_eval_core(emb: jnp.ndarray, crisp_raw: jnp.ndarray,
             emb, t["centroids"], t["classifier_mask"].astype(f32),
             t["col_scale"], t["col_thr"], t["grouped_mask"],
             t["member_full"], t["default_full"], qscale=t["qscale"],
-            interpret=interpret)
+            block_b=block_b, interpret=interpret)
     else:
         raw_p, normalized_p, fired_p = _signal_eval_unfused(
             emb, t, kernel_mode=kernel_mode, interpret=interpret)
@@ -259,6 +261,7 @@ def _signal_eval_unfused(emb: jnp.ndarray, t: Dict[str, jnp.ndarray], *,
         c = unpack_int4(c, emb.shape[1])
     sims = jax.lax.dot_general(                      # the single GEMM (B, N)
         emb, c.astype(f32), (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=f32) * t["qscale"][None, :]
     raw_p = jnp.where(t["classifier_mask"][None, :],
                       (sims + 1.0) * 0.5, sims)
@@ -297,7 +300,7 @@ def _signal_eval_unfused(emb: jnp.ndarray, t: Dict[str, jnp.ndarray], *,
 # jit-cached once per (shape-signature, flags) across every engine instance
 _SIGNAL_EVAL = jax.jit(_signal_eval_core,
                        static_argnames=("kernel_mode", "interpret",
-                                        "nprobe"))
+                                        "nprobe", "block_b"))
 
 KERNEL_MODES = ("auto", "jnp", "grouped", "fused", "fused_dtiled",
                 "ivf", "ivf_fused")
@@ -310,7 +313,7 @@ def resolve_kernel_mode(kernel: Optional[str], use_pallas: bool) -> str:
     is emulation-slow on CPU); ``use_pallas=True`` keeps its PR 1
     meaning of the grouped-Voronoi kernel.  A resolved ``fused`` may be
     upgraded to ``fused_dtiled`` at bind time when the centroid store
-    exceeds the VMEM budget (kernels/ops.select_fused_variant)."""
+    exceeds the VMEM budget (kernels/ops.select_fused_plan)."""
     if kernel is not None and kernel != "auto":
         if kernel not in KERNEL_MODES:
             raise ValueError(f"kernel must be one of {KERNEL_MODES}, "
@@ -376,6 +379,7 @@ def _sharded_route_body(model_axis: Optional[str],
         else:
             sims = jax.lax.dot_general(
                 x.astype(f32), c.astype(f32), (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=f32) * qs              # (Bl, Nl)
         hooks = {}
         col_offset = 0
@@ -406,13 +410,23 @@ def mesh_model_size(mesh: Mesh) -> int:
     return mesh.shape.get("model", 1)
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis in Auto mode.  The route programs leave
+    the layout of what they return to the compiler's sharding
+    propagation; ``jax.make_mesh`` builds Explicit axes, under which
+    every scatter into and slice of a sharded result would have to name
+    its own out_sharding."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 @functools.lru_cache(maxsize=32)
 def _sharded_route_raw(mesh: Mesh, body_kernel: str = "jnp",
                        interpret: bool = False):
     """Jitted shard_map of the fused_route contract over ``mesh``:
     inputs must already be padded to (data-multiple B, model-multiple
     N).  Cached per (mesh, body lowering)."""
-    from jax.experimental.shard_map import shard_map
+    mesh = auto_axes(mesh)
     daxes = _mesh_batch_axes(mesh)
     maxis = "model" if "model" in mesh.shape else None
     bspec = P(daxes if daxes else None, None)
@@ -420,12 +434,12 @@ def _sharded_route_raw(mesh: Mesh, body_kernel: str = "jnp",
     rspec = P(None, maxis)
     ospec = P(daxes if daxes else None, maxis)
     wspec = P(daxes if daxes else None, None)
-    sh = shard_map(
+    sh = jax.shard_map(
         _sharded_route_body(maxis, body_kernel, interpret), mesh=mesh,
         in_specs=(bspec, cspec, rspec, rspec, rspec, rspec, rspec,
                   rspec, rspec),
         out_specs=(ospec, ospec, ospec, wspec, wspec),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sh)
 
 
@@ -539,6 +553,8 @@ class SignalEngine:
             raise ValueError("precision='int4' is not supported with a "
                              "mesh; use int8 for sharded stores")
         self.interpret = ops.default_interpret()
+        # query-row block of the flat fused kernels (bind-time choice)
+        self.block_b = ops.ROW_BLOCKS[0]
         self.body_kernel = resolve_body_kernel(body_kernel)
         self._two_stage_req = two_stage
         self._nprobe_req = nprobe
@@ -560,7 +576,7 @@ class SignalEngine:
             # int4), not the f32 image — the satellite fix that keeps
             # quantized stores resident up to their true footprint.
             store = self.tensors["centroids"]
-            self.kernel_mode = ops.select_fused_variant(
+            self.kernel_mode, self.block_b = ops.select_fused_plan(
                 store.shape[0], self._embed_dim,
                 self.tensors["member_full"].shape[0],
                 centroid_bytes=ops.precision_centroid_bytes(
@@ -880,7 +896,7 @@ class SignalEngine:
             raw, normalized, fired, conf = _SIGNAL_EVAL(
                 jnp.asarray(emb), jnp.asarray(crisp), self.tensors,
                 kernel_mode=self.kernel_mode, interpret=self.interpret,
-                nprobe=self.nprobe)
+                nprobe=self.nprobe, block_b=self.block_b)
         return SignalBatchResult(
             list(self.names), np.asarray(raw), np.asarray(normalized),
             np.asarray(fired), np.asarray(conf))

@@ -43,13 +43,19 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-# slab widths round up to this so fine-stage tiles stay lane-friendly
+# smallest chunk a cluster is split into
 SLAB_ALIGN = 8
+# slab widths round up to the TPU lane width: the fine-stage kernel
+# stages probe p's slab at lane offset p·slab_k, and Mosaic stores at a
+# dynamic lane offset only when it is a multiple of 128
+SLAB_LANES = 128
 
 
 # ---------------------------------------------------------------------------
-# int4 packing: two's-complement nibbles, column 2j in the low nibble of
-# byte j, column 2j+1 in the high nibble (odd D pads a zero column)
+# int4 packing: two's-complement nibbles, column j in the low nibble of
+# byte j and column P+j in the high nibble, P = ceil(D/2) (odd D pads a
+# zero column).  Unpacking is then a lane concatenation of the two
+# halves, which the TPU kernels do per tile without an interleave.
 # ---------------------------------------------------------------------------
 
 
@@ -59,8 +65,9 @@ def pack_int4(q: np.ndarray) -> np.ndarray:
     n, d = q.shape
     if d % 2:
         q = np.concatenate([q, np.zeros((n, 1), np.int8)], axis=1)
-    lo = q[:, 0::2].astype(np.uint8) & 0xF
-    hi = q[:, 1::2].astype(np.uint8) & 0xF
+    p = q.shape[1] // 2
+    lo = q[:, :p].astype(np.uint8) & 0xF
+    hi = q[:, p:].astype(np.uint8) & 0xF
     return (lo | (hi << 4)).astype(np.uint8)
 
 
@@ -71,8 +78,7 @@ def unpack_int4(packed: np.ndarray, d: int) -> np.ndarray:
     lo = lo - np.where(lo > 7, 16, 0)
     hi = (p >> 4).astype(np.int32)
     hi = hi - np.where(hi > 7, 16, 0)
-    out = np.stack([lo, hi], axis=-1).reshape(p.shape[0], -1)
-    return out[:, :d].astype(np.float32)
+    return np.concatenate([lo, hi], axis=1)[:, :d].astype(np.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -145,7 +151,7 @@ def build_slab_layout(assign: np.ndarray, k: int
     if not chunks:
         chunks = [np.zeros(0, np.int32)]
     width = max(int(ch.size) for ch in chunks)
-    slab_k = SLAB_ALIGN * max(1, math.ceil(width / SLAB_ALIGN))
+    slab_k = SLAB_LANES * max(1, math.ceil(width / SLAB_LANES))
     return chunks, slab_k
 
 

@@ -318,10 +318,45 @@ def test_select_fused_variant_budget():
                                     budget_bytes=1 << 10) == "jnp"
     # int8 store is 4x smaller: a shape that spills in f32 can stay
     # resident at centroid_bytes=1
-    n, d = 768, 4096
+    n, d = 512, 4096
     assert ops.select_fused_variant(n, d, centroid_bytes=4) \
         == "fused_dtiled"
     assert ops.select_fused_variant(n, d, centroid_bytes=1) == "fused"
+
+
+def test_select_fused_plan_narrows_the_row_block():
+    """A table too wide for a resident 128-row block keeps the resident
+    kernel at the widest narrower block that fits, before the D-tiled
+    variant or jnp are considered."""
+    assert ops.select_fused_plan(64, 256) == ("fused", 128)
+    n = 3000
+    assert ops.select_fused_variant(n, 256) == "jnp"
+    variant, bb = ops.select_fused_plan(n, 256)
+    assert variant == "fused" and bb < 128
+    assert ops.select_fused_variant(n, 256, block_b=bb) == "fused"
+    assert ops.select_fused_variant(n, 256, block_b=2 * bb) != "fused"
+    assert ops.select_fused_plan(512, 16384) == ("fused_dtiled", 128)
+    assert ops.select_fused_plan(64, 256, budget_bytes=1 << 10) \
+        == ("jnp", 128)
+
+
+def test_engine_routes_a_narrow_block_table_like_jnp():
+    """A table past the 128-row resident budget binds the fused kernel
+    at a narrower block; a batch wider than that block tiles by it and
+    routes exactly as the jnp lowering does."""
+    import pathlib
+    import sys
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
+    from benchmarks.bench_router import make_dsl
+    from repro.serving.router import RouterService
+    dsl = make_dsl(3000)
+    svc = {k: RouterService(dsl, load_backends=False, validate=False,
+                            kernel=k) for k in ("fused", "jnp")}
+    eng = svc["fused"].engine
+    assert eng.kernel_mode == "fused" and eng.block_b < 128
+    queries = [f"query about topic {i} alpha" for i in range(eng.block_b + 8)]
+    np.testing.assert_array_equal(svc["fused"].route_indices(queries),
+                                  svc["jnp"].route_indices(queries))
 
 
 # ---------------------------------------------------------------------------
