@@ -198,12 +198,14 @@ def sharded_route_policy(mesh: Mesh, n_rules: int,
     pol_tail = _policy_argmax_body(maxis, n_rules, n_model)
 
     def body(emb, crisp_raw, st, pt):
-        _, scores, fired, _, _ = sig_body(
-            emb, st["centroids"], st["qscale_row"], st["cls_row"],
-            st["scale_row"], st["thr_row"], st["grp_row"],
-            st["member_row"], st["default_row"])
-        conf = jnp.where(fired, scores, 0.0)
-        return pol_tail(fired, conf, crisp_raw, st["thr_crisp"], pt)
+        with jax.named_scope("signal"):
+            _, scores, fired, _, _ = sig_body(
+                emb, st["centroids"], st["qscale_row"], st["cls_row"],
+                st["scale_row"], st["thr_row"], st["grp_row"],
+                st["member_row"], st["default_row"])
+            conf = jnp.where(fired, scores, 0.0)
+        with jax.named_scope("policy"):
+            return pol_tail(fired, conf, crisp_raw, st["thr_crisp"], pt)
 
     bspec = P(daxes if daxes else None, None)
     rspec = P(None, maxis)

@@ -93,6 +93,7 @@ def build_tables(cfg: RouterConfig) -> PolicyTables:
         n_rules=len(cfg.rules))
 
 
+@jax.named_scope("policy")
 def evaluate_policy(tables: Dict[str, jnp.ndarray], n_rules: int,
                     fired: jnp.ndarray, confidence: jnp.ndarray
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:

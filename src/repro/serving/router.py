@@ -91,6 +91,7 @@ from repro.serving.batcher import (Batcher, ContinuousBatcher, Request,
                                    finish_request)
 from repro.serving.brownout import BrownoutConfig, BrownoutController
 from repro.serving.faults import (BreakerConfig, FaultManager, RetryPolicy)
+from repro.serving.spans import PhaseCounters
 from repro.signals import engine as engine_mod
 from repro.signals.embedder import HashEmbedder
 
@@ -193,6 +194,8 @@ class PolicyGeneration:
     # built only when the engine's shard_map path is active, so the
     # non-observing mesh route can psum_scatter the policy argmax
     pshard: Optional[Dict[str, jnp.ndarray]] = None
+    # seconds per build phase (``router.build.*``) of this generation
+    build_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -315,6 +318,10 @@ class RouterService:
         # monitor default follows audit: observability on or off as one
         self._monitor_enabled = bool(audit) if monitor is None \
             else bool(monitor)
+        # route-call phases (serving/spans.py) and the rows they routed
+        self._route_phases = PhaseCounters()
+        self._route_rows = 0
+        self._route_bucket_rows = 0
         self.faults = FaultManager(
             retry=retry, breaker=breaker,
             clock=lambda: self.cbatcher.clock(), seed=fault_seed,
@@ -392,37 +399,48 @@ class RouterService:
                           validate: bool = True,
                           run_taxonomy: bool = False) -> PolicyGeneration:
         from repro.signals.engine import SignalEngine
-        config = compile_text(dsl_text)
+        build = PhaseCounters()
+        with build.phase("router.build.compile"):
+            config = compile_text(dsl_text)
         diagnostics: List[Diagnostic] = []
         if validate:
-            diagnostics = Validator(config).validate(
-                run_taxonomy=run_taxonomy)
+            with build.phase("router.build.validate"):
+                diagnostics = Validator(config).validate(
+                    run_taxonomy=run_taxonomy)
             if has_errors(diagnostics):
                 msgs = "\n".join(str(d) for d in diagnostics
                                  if d.severity == "error")
                 raise ValueError(f"config has validation errors:\n{msgs}")
-        engine = SignalEngine(config, self.embedder, **self._engine_opts)
-        tables = policy_mod.build_tables(config)
+        with build.phase("router.build.engine"):
+            engine = SignalEngine(config, self.embedder, **self._engine_opts)
+        with build.phase("router.build.tables"):
+            tables = policy_mod.build_tables(config)
         mon = None
         if self._monitor_enabled:
             mon = OnlineConflictMonitor(
                 engine.names, priority_of=self._atom_priorities(config))
-        pshard = None
-        if engine.sharded_active:
-            from repro.distributed import policy_shard as pshard_mod
-            pshard = {
-                k: jnp.asarray(v)
-                for k, v in pshard_mod.build_policy_shard_tables(
-                    tables,
-                    prob_cols=np.asarray(engine.tensors["prob_cols"]),
-                    crisp_cols=np.asarray(engine.tensors["crisp_cols"]),
-                    n_model=engine_mod.mesh_model_size(
-                        engine.mesh)).items()}
+        with build.phase("router.build.upload"):
+            jt = tables.as_jax()
+            pshard = None
+            if engine.sharded_active:
+                from repro.distributed import policy_shard as pshard_mod
+                pshard = {
+                    k: jnp.asarray(v)
+                    for k, v in pshard_mod.build_policy_shard_tables(
+                        tables,
+                        prob_cols=np.asarray(engine.tensors["prob_cols"]),
+                        crisp_cols=np.asarray(engine.tensors["crisp_cols"]),
+                        n_model=engine_mod.mesh_model_size(
+                            engine.mesh)).items()}
+            # the span times the copies, not their enqueue
+            jax.block_until_ready((jt, pshard))
         return PolicyGeneration(
             gen_id=gen_id, config=config, engine=engine, tables=tables,
-            jt=tables.as_jax(), diagnostics=diagnostics,
+            jt=jt, diagnostics=diagnostics,
             fingerprint=config.fingerprint(), monitor=mon,
-            pshard=pshard)
+            pshard=pshard,
+            build_s={k: v["seconds"]
+                     for k, v in build.snapshot().items()})
 
     @staticmethod
     def _atom_priorities(config: RouterConfig) -> Dict[str, int]:
@@ -647,7 +665,14 @@ class RouterService:
 
         Batches are padded up to the next power-of-two bucket so the
         jit cache compiles one variant per power of two up to the
-        largest batch seen (instead of one per distinct batch size)."""
+        largest batch seen (instead of one per distinct batch size).
+
+        Each call runs as the ``router.route`` phase, and its steps as
+        ``router.embed``, ``router.pack`` (crisp scores, padding, the
+        host-to-device copies), ``router.dispatch`` (the program's
+        asynchronous launch), ``router.fetch`` (the wait for and copy
+        of the results) and ``router.observe`` (monitor and audit):
+        profiler spans and counters, ``telemetry()["route"]``."""
         gen = gen or self._gen
         if not texts:
             # (b-1).bit_length() on b == 0 would pad a phantom row and
@@ -655,58 +680,68 @@ class RouterService:
             return np.zeros((0,), np.int64), np.zeros((0,), np.float32)
         observe = gen.monitor is not None or self.audit is not None
         engine = gen.engine
+        ph = self._route_phases
+        b = len(texts)
+        bucket = b
         if engine.fused_ok:
-            b = len(texts)
-            emb = engine.embed(texts)
-            crisp = engine.crisp_scores(texts, metadata)
             bucket = 1 << max(0, (b - 1).bit_length())
             if engine.sharded_active:
                 # keep buckets divisible by the mesh's data axes so the
                 # batch shards instead of replicating
                 dsz = engine_mod.mesh_data_size(engine.mesh)
                 bucket += (-bucket) % dsz
-            if bucket != b:
-                pad = ((0, bucket - b), (0, 0))
-                emb = np.pad(emb, pad)
-                crisp = np.pad(crisp, pad)
-            if engine.sharded_active and not observe \
-                    and gen.pshard is not None:
-                # nothing observes: psum_scatter the policy argmax —
-                # fired/conf stay sharded, only (B,) vectors cross the
-                # mesh (distributed/policy_shard)
-                from repro.distributed import policy_shard as pshard_mod
-                idx, score = pshard_mod.sharded_route_policy(
-                    engine.mesh, gen.tables.n_rules,
-                    engine.body_kernel, engine.interpret)(
-                    jnp.asarray(emb), jnp.asarray(crisp),
-                    engine.sharded_tensors, gen.pshard)
-                return np.asarray(idx)[:b], np.asarray(score)[:b]
-            if engine.sharded_active:
-                idx, score, norm, fired = _sharded_route_core(
-                    engine.mesh, gen.tables.n_rules,
-                    engine.body_kernel, engine.interpret)(
-                    jnp.asarray(emb), jnp.asarray(crisp),
-                    engine.sharded_tensors, gen.jt)
-            else:
-                idx, score, norm, fired = _route_core(
-                    jnp.asarray(emb), jnp.asarray(crisp), engine.tensors,
-                    gen.jt, gen.tables.n_rules,
-                    kernel_mode=engine.kernel_mode,
-                    interpret=engine.interpret, nprobe=engine.nprobe,
-                    block_b=engine.block_b)
-            idx = np.asarray(idx)[:b]
-            score = np.asarray(score)[:b]
+        self._route_rows += b
+        self._route_bucket_rows += bucket
+        with ph.phase("router.route", rows=b, bucket=bucket,
+                      call=ph.count("router.route")):
+            if not engine.fused_ok:
+                res = engine.evaluate(texts, metadata)
+                idx, score = policy_mod.evaluate_indices(
+                    gen.tables, res.fired, res.confidence)
+                if observe:
+                    self._observe(gen, texts, idx, score, res.normalized,
+                                  res.fired)
+                return idx, score
+            with ph.phase("router.embed"):
+                emb = engine.embed(texts)
+            with ph.phase("router.pack"):
+                crisp = engine.crisp_scores(texts, metadata)
+                if bucket != b:
+                    pad = ((0, bucket - b), (0, 0))
+                    emb = np.pad(emb, pad)
+                    crisp = np.pad(crisp, pad)
+                emb, crisp = jnp.asarray(emb), jnp.asarray(crisp)
+            with ph.phase("router.dispatch"):
+                if engine.sharded_active and not observe \
+                        and gen.pshard is not None:
+                    # nothing observes: psum_scatter the policy argmax —
+                    # fired/conf stay sharded, only (B,) vectors cross
+                    # the mesh (distributed/policy_shard)
+                    from repro.distributed import policy_shard as pshard_mod
+                    idx, score = pshard_mod.sharded_route_policy(
+                        engine.mesh, gen.tables.n_rules,
+                        engine.body_kernel, engine.interpret)(
+                        emb, crisp, engine.sharded_tensors, gen.pshard)
+                elif engine.sharded_active:
+                    idx, score, norm, fired = _sharded_route_core(
+                        engine.mesh, gen.tables.n_rules,
+                        engine.body_kernel, engine.interpret)(
+                        emb, crisp, engine.sharded_tensors, gen.jt)
+                else:
+                    idx, score, norm, fired = _route_core(
+                        emb, crisp, engine.tensors, gen.jt,
+                        gen.tables.n_rules, kernel_mode=engine.kernel_mode,
+                        interpret=engine.interpret, nprobe=engine.nprobe,
+                        block_b=engine.block_b)
+            with ph.phase("router.fetch"):
+                idx = np.asarray(idx)[:b]
+                score = np.asarray(score)[:b]
             if observe:
-                self._observe(gen, texts, idx, score,
-                              np.asarray(norm)[:b], np.asarray(fired)[:b])
+                with ph.phase("router.observe"):
+                    self._observe(gen, texts, idx, score,
+                                  np.asarray(norm)[:b],
+                                  np.asarray(fired)[:b])
             return idx, score
-        res = engine.evaluate(texts, metadata)
-        idx, score = policy_mod.evaluate_indices(gen.tables, res.fired,
-                                                 res.confidence)
-        if observe:
-            self._observe(gen, texts, idx, score, res.normalized,
-                          res.fired)
-        return idx, score
 
     def _observe(self, gen: PolicyGeneration, texts, idx, score,
                  normalized, fired) -> None:
@@ -1115,7 +1150,12 @@ class RouterService:
             backend), ``generations`` (hot-swap refcounts), and
             ``audit`` (records logged per kind), plus ``ingress``
             (overload counters: accepted/shed/timed_out/cancelled and
-            the current ``brownout_level``).
+            the current ``brownout_level``), ``route`` (cumulative:
+            ``calls``, ``rows`` routed, ``bucket_rows`` after padding,
+            and ``phases``, per ``router.*`` phase of ``_route_eval``
+            its ``n``, ``seconds`` and ``over_100ms``) and ``build``
+            (seconds per ``router.build.*`` phase of the serving
+            generation's build).
         """
         out: Dict[str, Any] = {
             "queue_depth": {b: len(q) for b, q in
@@ -1125,6 +1165,11 @@ class RouterService:
             "ingress": {**self.overload,
                         "brownout_level": (self.brownout.level
                                            if self.brownout else 0)},
+            "route": {"calls": self._route_phases.count("router.route"),
+                      "rows": self._route_rows,
+                      "bucket_rows": self._route_bucket_rows,
+                      "phases": self._route_phases.snapshot()},
+            "build": dict(self._gen.build_s),
         }
         if self.scheduler is not None:
             out["scheduler"] = dict(self.scheduler.stats)

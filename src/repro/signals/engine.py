@@ -178,6 +178,7 @@ def _device_tables(np_tensors: Dict[str, np.ndarray], *,
     return out
 
 
+@jax.named_scope("signal")
 def _signal_eval_core(emb: jnp.ndarray, crisp_raw: jnp.ndarray,
                       t: Dict[str, jnp.ndarray], *,
                       kernel_mode: str, interpret: bool, nprobe: int = 1,
@@ -500,6 +501,7 @@ def _sharded_signal_eval(mesh: Mesh, body_kernel: str = "jnp",
     sh = _sharded_route_raw(mesh, body_kernel, interpret)
 
     @jax.jit
+    @jax.named_scope("signal")
     def fn(emb, crisp_raw, st):
         f32 = jnp.float32
         raw_pp, norm_pp, fired_pp, _, _ = sh(
