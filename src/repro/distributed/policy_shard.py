@@ -14,7 +14,7 @@ ever sees the full fired matrix or the full term matrix.
 
 Exactness.  ``got``/``blocked`` are sums of 0/1 indicators in f32, so
 every partial is integer-valued and the psum is order-independent and
-bitwise-equal to the replicated GEMM.  Term confidence is a max (not a
+bitwise-equal to the replicated count.  Term confidence is a max (not a
 sum), so it rides ``all_to_all`` + a local max over source devices —
 also order-independent.  The winner is then the staged lexicographic
 argmax evaluated in *term space*: every term of a rule carries the
@@ -48,7 +48,8 @@ BIG_RULE = np.int32(2 ** 30)    # pmin identity for the rule-index race
 
 def build_policy_shard_tables(tables, *, prob_cols, crisp_cols,
                               n_model: int) -> Dict[str, np.ndarray]:
-    """Lower ``PolicyTables`` to the rule-aligned sharded term layout.
+    """Lower ``PolicyTables``' literal-index lists to the rule-aligned
+    sharded term layout.
 
     prob_cols/crisp_cols: the engine's signal-column indices (policy
     atom axis order is ``sorted(cfg.signals)`` — the same order the
@@ -66,19 +67,19 @@ def build_policy_shard_tables(tables, *, prob_cols, crisp_cols,
     crisp_cols = np.asarray(crisp_cols, np.int64)
     n_prob = prob_cols.shape[0]
     npad = n_prob + (-n_prob) % max(n_model, 1)
-    t_total = tables.pos.shape[0]
-    term_rule = np.asarray(tables.term_rule, np.int64)
+    terms = [tables.terms(r) for r in range(tables.n_rules)]
+    t_total = sum(len(t) for t in terms)
 
     # contiguous whole-rule partition, proportionally balanced: rule r
     # (terms [lo, hi)) lands in the chunk its term midpoint falls in
     chunks: list = [[] for _ in range(n_model)]
     lo = 0
-    for r in range(tables.n_rules):
-        hi = lo + int((term_rule == r).sum())
+    for r, rule_terms in enumerate(terms):
+        hi = lo + len(rule_terms)
         if hi > lo:
             k = min(n_model - 1,
                     int((lo + hi - 1) // 2 * n_model / max(t_total, 1)))
-            chunks[k].extend(range(lo, hi))
+            chunks[k].extend((r, p, n) for p, n in rule_terms)
         lo = hi
     tc = max(1, max(len(c) for c in chunks))
 
@@ -92,16 +93,21 @@ def build_policy_shard_tables(tables, *, prob_cols, crisp_cols,
     tier_t = np.zeros((tp,), np.float32)
     pri_t = np.zeros((tp,), np.float32)
     rule_t = np.full((tp,), BIG_RULE, np.int32)
-    for k, terms in enumerate(chunks):
-        for j, ti in enumerate(terms):
+    # policy atom -> its prob / crisp signal column (an atom bound to
+    # neither still counts toward ``need``, as in evaluate_policy)
+    prob_of = {int(a): j for j, a in enumerate(prob_cols)}
+    crisp_of = {int(a): j for j, a in enumerate(crisp_cols)}
+    for k, chunk in enumerate(chunks):
+        for j, (ri, p, n) in enumerate(chunk):
             row = k * tc + j
-            pos_prob[row, :n_prob] = tables.pos[ti, prob_cols]
-            neg_prob[row, :n_prob] = tables.neg[ti, prob_cols]
-            if ac:
-                pos_crisp[row] = tables.pos[ti, crisp_cols]
-                neg_crisp[row] = tables.neg[ti, crisp_cols]
-            need[row] = tables.pos[ti].sum()
-            ri = int(term_rule[ti])
+            for atoms, prob_t, crisp_t in ((p, pos_prob, pos_crisp),
+                                           (n, neg_prob, neg_crisp)):
+                for a in atoms.tolist():
+                    if a in prob_of:
+                        prob_t[row, prob_of[a]] = 1.0
+                    elif a in crisp_of:
+                        crisp_t[row, crisp_of[a]] = 1.0
+            need[row] = len(p)
             tier_t[row] = tables.tier[ri]
             pri_t[row] = tables.priority[ri]
             rule_t[row] = ri

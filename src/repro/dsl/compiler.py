@@ -3,8 +3,8 @@
 RouterConfig is the single runtime artifact: signal atoms (with group
 membership), Voronoi groups, prioritized rules + actions, backends,
 plugins, TEST suites, and validated DECISION_TREEs.  The serving layer
-additionally lowers it to dense policy tables (serving/policy.py) so a
-whole request batch routes with one jit'd evaluation.
+additionally lowers it to literal-index policy tables (serving/policy.py)
+so a whole request batch routes with one jit'd evaluation.
 """
 from __future__ import annotations
 

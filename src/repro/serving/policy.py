@@ -1,9 +1,10 @@
 """Tensorized policy evaluation.
 
-The DSL compiler's rule list is lowered once to dense tables (DNF literal
-masks, priority/tier vectors), so routing an entire request batch is one
-jit'd evaluation — the TPU-idiomatic replacement for a per-request
-first-match interpreter (DESIGN §3).  Semantics preserved exactly:
+The DSL compiler's rule list is lowered once to literal-index tables
+(each rule's DNF terms, each term's atom indices, priority/tier
+vectors), so routing an entire request batch is one jit'd evaluation —
+the TPU-idiomatic replacement for a per-request first-match interpreter
+(DESIGN §3).  Semantics preserved exactly:
 
   winner = argmax over fired rules of (tier, priority, confidence)
   confidence = max normalized score over the matched rule's positive atoms
@@ -13,6 +14,15 @@ TIER routing (paper §5, item 5): tiers dominate priority; within a tier,
 priority dominates confidence; equal-priority ties break on confidence —
 "priority-then-confidence".
 
+Layout.  Terms are grouped by rule and padded to rectangles whose
+widths come from the compiled policy: M (most terms of any rule), Kp
+and Kn (most positive / negative literals of any term).  A padded
+literal slot points at atom column A, appended at evaluation time as
+"never fired, confidence 0"; a padded term needs ``PAD_NEED`` positive
+literals, so it never holds.  The tables grow with the literals, not
+with terms x atoms: at 16k one-atom routes a call gathers 16k rows of
+the (A+1, 2B) operand where a (term, atom) mask would stream 1 GB.
+
 The jitted evaluator is cached at module level and ``PolicyTables``
 caches its device-resident view, so per-batch work is exactly one cached
 XLA call — no retracing, no host->device table transfer.
@@ -20,7 +30,7 @@ XLA call — no retracing, no host->device table transfer.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +39,7 @@ import numpy as np
 from repro.core.conditions import to_dnf_atoms
 from repro.dsl.compiler import RouterConfig
 
-BIG = 1024.0 * 1024.0
+PAD_NEED = np.int32(2 ** 30)    # a padded term can never be met
 
 
 @dataclasses.dataclass
@@ -37,9 +47,9 @@ class PolicyTables:
     atom_names: List[str]
     rule_names: List[str]
     actions: List[str]            # per rule, + [default] at index n_rules
-    pos: np.ndarray               # (T, A) term requires atom fired
-    neg: np.ndarray               # (T, A) term requires atom NOT fired
-    term_rule: np.ndarray         # (T,) owning rule index
+    pos_idx: np.ndarray           # (R, M, Kp) positive literals' atoms
+    neg_idx: np.ndarray           # (R, M, Kn) negative literals' atoms
+    need: np.ndarray              # (R, M) positive literals per term
     priority: np.ndarray          # (R,)
     tier: np.ndarray              # (R,)
     n_rules: int
@@ -51,9 +61,33 @@ class PolicyTables:
         hit the same buffers on every batch."""
         if self._jax is None:
             self._jax = {k: jnp.asarray(getattr(self, k))
-                         for k in ("pos", "neg", "term_rule", "priority",
-                                   "tier")}
+                         for k in ("pos_idx", "neg_idx", "need",
+                                   "priority", "tier")}
         return self._jax
+
+    def terms(self, r: int) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """Rule ``r``'s DNF terms in order, each as (positive atom
+        indices, negative atom indices) without padding."""
+        pad = len(self.atom_names)
+        return [(p[p != pad], n[n != pad]) for p, n, k in
+                zip(self.pos_idx[r], self.neg_idx[r], self.need[r])
+                if k != PAD_NEED]
+
+    def stats(self) -> Dict[str, Any]:
+        """Shape of the compiled DNF: ``rules``, real ``terms`` and
+        ``literals``, the padding widths ``M``/``Kp``/``Kn``, and
+        ``pad_share``, the share of gathered literal slots that are
+        padding."""
+        pad = len(self.atom_names)
+        literals = int((self.pos_idx != pad).sum()
+                       + (self.neg_idx != pad).sum())
+        slots = self.pos_idx.size + self.neg_idx.size
+        _, m, kp = self.pos_idx.shape
+        return {"rules": self.n_rules,
+                "terms": int((self.need != PAD_NEED).sum()),
+                "literals": literals, "M": m, "Kp": kp,
+                "Kn": self.neg_idx.shape[2],
+                "pad_share": 1.0 - literals / slots if slots else 0.0}
 
     def action_key(self, i: int) -> str:
         return self.actions[int(i)]
@@ -66,28 +100,31 @@ class PolicyTables:
 def build_tables(cfg: RouterConfig) -> PolicyTables:
     atoms = sorted(cfg.signals)
     aidx = {a: i for i, a in enumerate(atoms)}
-    pos_rows, neg_rows, term_rule = [], [], []
-    rule_names, actions = [], []
-    for ri, rule in enumerate(cfg.rules):
+    rule_terms, rule_names, actions = [], [], []
+    for rule in cfg.rules:
         rule_names.append(rule.name)
         actions.append(cfg.actions[rule.name].key())
-        for (p, n) in to_dnf_atoms(rule.condition):
-            pr = np.zeros(len(atoms), np.float32)
-            nr = np.zeros(len(atoms), np.float32)
-            for a in p:
-                pr[aidx[a]] = 1.0
-            for a in n:
-                nr[aidx[a]] = 1.0
-            pos_rows.append(pr)
-            neg_rows.append(nr)
-            term_rule.append(ri)
+        rule_terms.append([(sorted(aidx[a] for a in p),
+                            sorted(aidx[a] for a in n))
+                           for p, n in to_dnf_atoms(rule.condition)])
     default = cfg.default_action
     actions.append(default.key() if default else "model:__reject__")
+    # widths from the policy itself; M and Kp keep one slot so the
+    # rule and confidence maxima always have an operand
+    m = max([1] + [len(t) for t in rule_terms])
+    kp = max([1] + [len(p) for t in rule_terms for p, _ in t])
+    kn = max([0] + [len(n) for t in rule_terms for _, n in t])
+    pos_idx = np.full((len(rule_terms), m, kp), len(atoms), np.int32)
+    neg_idx = np.full((len(rule_terms), m, kn), len(atoms), np.int32)
+    need = np.full((len(rule_terms), m), PAD_NEED, np.int32)
+    for r, terms in enumerate(rule_terms):
+        for j, (p, n) in enumerate(terms):
+            pos_idx[r, j, :len(p)] = p
+            neg_idx[r, j, :len(n)] = n
+            need[r, j] = len(p)
     return PolicyTables(
         atom_names=atoms, rule_names=rule_names, actions=actions,
-        pos=np.stack(pos_rows) if pos_rows else np.zeros((0, len(atoms)), np.float32),
-        neg=np.stack(neg_rows) if neg_rows else np.zeros((0, len(atoms)), np.float32),
-        term_rule=np.asarray(term_rule, np.int32),
+        pos_idx=pos_idx, neg_idx=neg_idx, need=need,
         priority=np.asarray([r.priority for r in cfg.rules], np.float32),
         tier=np.asarray([r.tier for r in cfg.rules], np.float32),
         n_rules=len(cfg.rules))
@@ -99,23 +136,30 @@ def evaluate_policy(tables: Dict[str, jnp.ndarray], n_rules: int,
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """fired/confidence: (B, A) -> (route_idx (B,), score (B,)).
     route_idx == n_rules means the default action."""
+    pos_idx, neg_idx = tables["pos_idx"], tables["neg_idx"]
+    r, m, kp = pos_idx.shape
+    kn = neg_idx.shape[2]
+    b = fired.shape[0]
+    # atom-major operand, rows = atoms + the pad atom A, columns = fired
+    # then confidence of every request: one row gather (the embedding-
+    # lookup shape) serves both the literal count and the confidence max
     f = fired.astype(jnp.float32)
-    pos, neg = tables["pos"], tables["neg"]
-    need = pos.sum(axis=1)                                   # (T,)
-    got = f @ pos.T                                          # (B, T)
-    blocked = f @ neg.T                                      # (B, T)
-    term_ok = (got >= need[None]) & (blocked <= 0.0)
-    # rule fires if any of its terms do
-    rule_ok = jnp.zeros((f.shape[0], n_rules), bool)
-    rule_ok = rule_ok.at[:, tables["term_rule"]].max(term_ok)
-    # rule confidence: max positive-atom confidence over satisfied terms
-    term_conf = jnp.where(
-        term_ok,
-        jnp.max(jnp.where(pos[None] > 0, confidence[:, None, :], 0.0),
-                axis=-1),
-        0.0)
-    rule_conf = jnp.zeros((f.shape[0], n_rules), term_conf.dtype)
-    rule_conf = rule_conf.at[:, tables["term_rule"]].max(term_conf)
+    lit = jnp.concatenate([f, confidence.astype(jnp.float32)], axis=0).T
+    lit = jnp.pad(lit, ((0, 1), (0, 0)))                     # (A+1, 2B)
+    g = jnp.take(lit, pos_idx.reshape(-1), axis=0, mode="clip"
+                 ).reshape(r, m, kp, 2 * b)
+    got = g[..., :b].sum(axis=2)                             # (R, M, B)
+    term_ok = got >= tables["need"].astype(jnp.float32)[..., None]
+    if kn:
+        blocked = jnp.take(lit[:, :b], neg_idx.reshape(-1), axis=0,
+                           mode="clip").reshape(r, m, kn, b).sum(axis=2)
+        term_ok = term_ok & (blocked <= 0.0)
+    # rule fires if any of its terms do; its confidence is the max
+    # positive-literal confidence over satisfied terms (0 when none,
+    # as is a padded slot's)
+    term_conf = jnp.where(term_ok, g[..., b:].max(axis=2), 0.0)
+    rule_ok = jnp.any(term_ok, axis=1).T                     # (B, R)
+    rule_conf = jnp.max(term_conf, axis=1).T.astype(confidence.dtype)
     # exact staged lexicographic argmax over (tier, priority, confidence):
     # a single scalarized score (tier*B^2 + pri*B + conf) loses the
     # confidence tie-break to f32 rounding at high tiers (found by

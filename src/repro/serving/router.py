@@ -1153,9 +1153,12 @@ class RouterService:
             the current ``brownout_level``), ``route`` (cumulative:
             ``calls``, ``rows`` routed, ``bucket_rows`` after padding,
             and ``phases``, per ``router.*`` phase of ``_route_eval``
-            its ``n``, ``seconds`` and ``over_100ms``) and ``build``
+            its ``n``, ``seconds`` and ``over_100ms``), ``build``
             (seconds per ``router.build.*`` phase of the serving
-            generation's build).
+            generation's build) and ``policy`` (the serving
+            generation's compiled-policy shape,
+            ``PolicyTables.stats``: rules, terms, literals, padding
+            widths ``M``/``Kp``/``Kn`` and ``pad_share``).
         """
         out: Dict[str, Any] = {
             "queue_depth": {b: len(q) for b, q in
@@ -1170,6 +1173,7 @@ class RouterService:
                       "bucket_rows": self._route_bucket_rows,
                       "phases": self._route_phases.snapshot()},
             "build": dict(self._gen.build_s),
+            "policy": self._gen.tables.stats(),
         }
         if self.scheduler is not None:
             out["scheduler"] = dict(self.scheduler.stats)
