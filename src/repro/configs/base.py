@@ -25,6 +25,7 @@ CROSS = "cross_attn"     # gated cross-attention (mllama image layers)
 # ffn kinds
 SWIGLU = "swiglu"
 GEGLU = "geglu"
+GEGLU_ERF = "geglu_erf"  # GeGLU with the exact (erf) GELU (ModernBERT)
 GELU_MLP = "gelu_mlp"
 MOE = "moe"
 RWKV_CM = "rwkv_cm"      # RWKV channel mix
@@ -39,7 +40,10 @@ class LayerSpec:
     window: Optional[int] = None      # sliding-window size (None = global)
     rope_theta: Optional[float] = None  # per-layer rope base override
     cross: bool = False               # additional cross-attn (whisper dec)
-    causal: bool = True
+    causal: bool = True               # False: bidirectional; a window then
+                                      # spans |i - j| <= window // 2
+    attn_norm: bool = True            # False: no norm before the mixer
+                                      # (ModernBERT's first layer)
 
     @property
     def is_recurrent(self) -> bool:
@@ -110,7 +114,7 @@ class ModelConfig:
     # remaining layers continue the unit pattern as an inline suffix.
     prefix: Tuple[LayerSpec, ...] = ()
     unit: Tuple[LayerSpec, ...] = (LayerSpec(),)
-    norm: str = "rmsnorm"             # rmsnorm | layernorm
+    norm: str = "rmsnorm"             # rmsnorm | layernorm | layernorm_nobias
     norm_eps: float = 1e-6
     post_norm: bool = False           # gemma-style post-block norms
     qk_norm: bool = False             # gemma3 qk rmsnorm
@@ -125,6 +129,8 @@ class ModelConfig:
     rwkv: Optional[RWKVConfig] = None
     encoder: Optional[EncoderConfig] = None
     vision: Optional[VisionConfig] = None
+    embed_norm: bool = False          # norm right after the token embedding
+    max_positions: int = 0            # longest input an encoder takes (0: any)
     dtype: str = "bfloat16"
     source: str = ""                  # citation from the assignment pool
     # runtime/perf toggles (see EXPERIMENTS.md §Perf)
@@ -216,7 +222,7 @@ class ModelConfig:
         raise ValueError(spec.mixer)
 
     def _ffn_params(self, spec: LayerSpec, d: int) -> int:
-        if spec.ffn in (SWIGLU, GEGLU):
+        if spec.ffn in (SWIGLU, GEGLU, GEGLU_ERF):
             return 3 * d * self.d_ff
         if spec.ffn == GELU_MLP:
             return 2 * d * self.d_ff

@@ -1,6 +1,7 @@
 """Self/cross attention: MHA, GQA, MQA; sliding windows; three prefill
 implementations (full, chunked online-softmax, banded windowed); ring-buffer
-decode caches.
+decode caches; bidirectional (encoder) attention over right-padded rows,
+global as the chunked online softmax and windowed as a symmetric band.
 
 Layout conventions:
   activations  x: (B, S, D)
@@ -88,10 +89,17 @@ def attend_full(q, k, v, q_pos, k_pos, *, causal: bool, window, scale, softcap=0
 
 
 def attend_chunked(q, k, v, q_pos, k_pos, *, causal: bool, window, scale,
-                   chunk: int, softcap=0.0):
-    """Online-softmax scan over KV chunks (flash-style, O(S*chunk) live)."""
+                   chunk: int, softcap=0.0, kv_len=None):
+    """Online-softmax scan over KV chunks (flash-style, O(S*chunk) live).
+
+    Non-causal: every query sees the keys at positions below its row's
+    ``kv_len`` (B,) (default: all of them).  A chunk whose keys are all
+    masked leaves the running state bit for bit as it was, so a row's
+    result does not depend on how far it is padded."""
     b, s, h, hd = q.shape
     t = k.shape[1]
+    if not causal and kv_len is None:
+        kv_len = jnp.full((b,), t, jnp.int32)
     nc = max(1, -(-t // chunk))
     pad = nc * chunk - t
     if pad:
@@ -112,6 +120,9 @@ def attend_chunked(q, k, v, q_pos, k_pos, *, causal: bool, window, scale,
         if causal:
             mask = _causal_mask(q_pos, pb, window)
             sb = jnp.where(mask[None, None, None], sb, NEG_INF)
+        else:
+            mask = pb[None, :] < kv_len[:, None]              # (B, C)
+            sb = jnp.where(mask[:, None, None, None], sb, NEG_INF)
         m_new = jnp.maximum(m, sb.max(axis=-1))
         r = jnp.exp(m - m_new)
         p = jnp.exp(sb - m_new[..., None])
@@ -161,15 +172,53 @@ def attend_banded(q, k, v, q_pos, k_pos, *, window: int, scale, softcap=0.0):
     return out.reshape(b, s, h, hd).astype(q.dtype)
 
 
+def attend_band(q, k, v, kv_len=None, *, window: int, scale):
+    """Bidirectional windowed attention: query i sees keys j with
+    |i - j| <= window // 2 and j below its row's ``kv_len`` (B,)
+    (default: all of them).  Only
+    the band is computed: the sequence is cut in blocks of window // 2,
+    and each query block scores the keys of its own block and the two
+    beside it, so the work is O(S * 1.5 window) for any length S."""
+    b, s, h, hd = q.shape
+    if kv_len is None:
+        kv_len = jnp.full((b,), s, jnp.int32)
+    half = window // 2
+    nb = -(-s // half)
+    pad = nb * half - s
+    kv_heads = k.shape[2]
+    pads = ((0, 0), (0, pad), (0, 0), (0, 0))
+    qb = jnp.pad(q, pads).reshape(b, nb, half, kv_heads, h // kv_heads, hd)
+
+    def three(x):                      # (B, nb, 3*half, KV, hd)
+        x = jnp.pad(x, ((0, 0), (half, pad + half), (0, 0), (0, 0)))
+        x = x.reshape(b, nb + 2, half, kv_heads, hd)
+        return jnp.concatenate([x[:, :-2], x[:, 1:-1], x[:, 2:]], axis=2)
+
+    k3, v3 = three(k), three(v)
+    sc = jnp.einsum("bnskgh,bntkh->bnkgst", qb, k3,
+                    preferred_element_type=jnp.float32) * scale
+    qp = jnp.arange(nb * half).reshape(nb, half)
+    kp = qp[:, :1] - half + jnp.arange(3 * half)[None]      # (nb, 3*half)
+    near = jnp.abs(qp[:, :, None] - kp[:, None, :]) <= half
+    mask = near[None] & (kp >= 0)[None, :, None] & (
+        kp[None, :, None, :] < kv_len[:, None, None, None])  # (B,nb,half,3h)
+    sc = jnp.where(mask[:, :, None, None], sc, NEG_INF)
+    p = jax.nn.softmax(sc, axis=-1)
+    out = jnp.einsum("bnkgst,bntkh->bnskgh", p.astype(v3.dtype), v3)
+    return out.reshape(b, nb * half, h, hd)[:, :s].astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Public layer application
 # ---------------------------------------------------------------------------
 
 def self_attention(p, cfg: ModelConfig, spec: LayerSpec, x, positions,
-                   *, cache=None, pos=None, collect: Optional[int] = None):
+                   *, cache=None, pos=None, collect: Optional[int] = None,
+                   kv_len=None):
     """cache=None -> train/prefill over full x.
     cache={'k','v'} + scalar pos -> single-token decode (x: (B,1,D)).
-    collect=max_seq -> prefill also returns a decode-ready KV cache."""
+    collect=max_seq -> prefill also returns a decode-ready KV cache.
+    kv_len (B,) -> a bidirectional layer's rows are right-padded past it."""
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     theta = spec.rope_theta or cfg.rope_theta
     scale = hd ** -0.5
@@ -184,10 +233,14 @@ def self_attention(p, cfg: ModelConfig, spec: LayerSpec, x, positions,
         k = cm.apply_rope(k, positions, theta, cfg.partial_rotary)
 
     if cache is None:
-        if not spec.causal:
-            out = attend_full(q, k, v, positions[0], positions[0],
-                              causal=False, window=None, scale=scale,
-                              softcap=cfg.logit_softcap)
+        if not spec.causal and spec.window is not None:
+            out = attend_band(q, k, v, kv_len, window=spec.window,
+                              scale=scale)
+        elif not spec.causal:
+            out = attend_chunked(q, k, v, positions[0], positions[0],
+                                 causal=False, window=None, scale=scale,
+                                 chunk=min(cfg.attn_chunk, x.shape[1]),
+                                 softcap=cfg.logit_softcap, kv_len=kv_len)
         elif (spec.window is not None and cfg.window_prefill_banded
               and x.shape[1] % spec.window == 0 and x.shape[1] > spec.window):
             out = attend_banded(q, k, v, positions[0], positions[0],

@@ -58,25 +58,27 @@ def rmsnorm(x, scale, eps: float = 1e-6, offset: bool = False):
     return y.astype(dt)
 
 
-def layernorm(x, scale, bias, eps: float = 1e-5):
+def layernorm(x, scale, bias=None, eps: float = 1e-5):
     dt = x.dtype
     x = x.astype(jnp.float32)
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    y = (x - mu) * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dt)
+    y = (x - mu) * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(dt)
 
 
 def init_norm(kind: str, d: int, dtype) -> Params:
-    if kind == "rmsnorm":
-        return {"scale": ones((d,), dtype)}
-    return {"scale": ones((d,), dtype), "bias": zeros((d,), dtype)}
+    if kind == "layernorm":
+        return {"scale": ones((d,), dtype), "bias": zeros((d,), dtype)}
+    return {"scale": ones((d,), dtype)}   # rmsnorm, layernorm_nobias
 
 
 def apply_norm(kind: str, p: Params, x, eps: float, gemma_offset: bool = False):
     if kind == "rmsnorm":
         return rmsnorm(x, p["scale"], eps, offset=gemma_offset)
-    return layernorm(x, p["scale"], p["bias"], eps)
+    return layernorm(x, p["scale"], p.get("bias"), eps)
 
 
 # ---------------------------------------------------------------------------

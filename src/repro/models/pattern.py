@@ -32,7 +32,9 @@ from repro.models import rwkv6 as rwkv_mod
 def init_block(key, cfg: ModelConfig, spec: LayerSpec) -> Dict[str, Any]:
     dt = cm.dtype_of(cfg.dtype)
     ks = jax.random.split(key, 6)
-    p: Dict[str, Any] = {"norm1": cm.init_norm(cfg.norm, cfg.d_model, dt)}
+    p: Dict[str, Any] = {}
+    if spec.attn_norm:
+        p["norm1"] = cm.init_norm(cfg.norm, cfg.d_model, dt)
     if spec.mixer == ATTN:
         p["mixer"] = attn.init_attention(ks[0], cfg, spec)
     elif spec.mixer == MLA:
@@ -92,22 +94,25 @@ def _norm(cfg, p, x, gemma_offset=False):
 
 
 def apply_block(p, cfg: ModelConfig, spec: LayerSpec, x, positions, *,
-                memory=None, cache=None, pos=None, collect=None):
+                memory=None, cache=None, pos=None, collect=None, kv_len=None):
     """-> (x, new_cache, moe_aux_loss).
 
     Modes: cache=None,collect=None -> train fwd; cache=None,collect=max_seq
     -> prefill emitting a decode-ready cache; cache set -> one-token decode.
+    ``kv_len`` (B,): real lengths of right-padded rows (bidirectional
+    layers mask the keys past them).
     """
     want_cache = cache is not None or collect is not None
     new_cache: Dict[str, Any] = {}
     aux = jnp.zeros((), jnp.float32)
     go = cfg.post_norm  # gemma-style (1+w) rmsnorm offset travels with it
-    h = _norm(cfg, p["norm1"], x, go)
+    h = _norm(cfg, p["norm1"], x, go) if spec.attn_norm else x
 
     if spec.mixer == ATTN:
         mix_cache = cache["mix"] if cache is not None else None
         h, nc = attn.self_attention(p["mixer"], cfg, spec, h, positions,
-                                    cache=mix_cache, pos=pos, collect=collect)
+                                    cache=mix_cache, pos=pos, collect=collect,
+                                    kv_len=kv_len)
         if nc is not None:
             new_cache["mix"] = nc
     elif spec.mixer == MLA:
@@ -219,7 +224,7 @@ def init_stack_cache(cfg: ModelConfig, batch: int, max_seq: int,
 
 
 def apply_stack(params, cfg: ModelConfig, x, positions, *, memory=None,
-                cache=None, pos=None, collect=None):
+                cache=None, pos=None, collect=None, kv_len=None):
     """-> (x, new_cache | None, total_moe_aux)."""
     prefix, n_units, suffix = cfg.pattern_decomposition()
     want_cache = cache is not None or collect is not None
@@ -231,7 +236,7 @@ def apply_stack(params, cfg: ModelConfig, x, positions, *, memory=None,
         x, nc, aux = apply_block(
             params["prefix"][i], cfg, spec, x, positions, memory=memory,
             cache=cache["prefix"][i] if cache else None, pos=pos,
-            collect=collect)
+            collect=collect, kv_len=kv_len)
         aux_total += aux
         if want_cache:
             new_cache["prefix"].append(nc)
@@ -246,7 +251,7 @@ def apply_stack(params, cfg: ModelConfig, x, positions, *, memory=None,
                 h, nc, aux = apply_block(
                     u_params[i], cfg, spec, h, positions, memory=memory,
                     cache=u_cache[i] if u_cache is not None else None,
-                    pos=pos, collect=collect)
+                    pos=pos, collect=collect, kv_len=kv_len)
                 ncs.append(nc)
                 aux_u += aux
             return h, (ncs, aux_u) if want_cache else aux_u
@@ -268,7 +273,7 @@ def apply_stack(params, cfg: ModelConfig, x, positions, *, memory=None,
         x, nc, aux = apply_block(
             params["suffix"][i], cfg, spec, x, positions, memory=memory,
             cache=cache["suffix"][i] if cache else None, pos=pos,
-            collect=collect)
+            collect=collect, kv_len=kv_len)
         aux_total += aux
         if want_cache:
             new_cache["suffix"].append(nc)

@@ -708,7 +708,9 @@ class RouterService:
                 crisp = engine.crisp_scores(texts, metadata)
                 if bucket != b:
                     pad = ((0, bucket - b), (0, 0))
-                    emb = np.pad(emb, pad)
+                    # an on-device embedder's rows stay on the device
+                    emb = (jnp.pad if isinstance(emb, jax.Array)
+                           else np.pad)(emb, pad)
                     crisp = np.pad(crisp, pad)
                 emb, crisp = jnp.asarray(emb), jnp.asarray(crisp)
             with ph.phase("router.dispatch"):
@@ -1158,7 +1160,11 @@ class RouterService:
             generation's build) and ``policy`` (the serving
             generation's compiled-policy shape,
             ``PolicyTables.stats``: rules, terms, literals, padding
-            widths ``M``/``Kp``/``Kn`` and ``pad_share``).
+            widths ``M``/``Kp``/``Kn`` and ``pad_share``), and, with
+            an on-device embedder, ``encoder`` (its launches, real and
+            launched tokens, ``pad_share``, launches per length bucket
+            and the ``router.encode`` phase;
+            ``EncoderEmbedder.telemetry``).
         """
         out: Dict[str, Any] = {
             "queue_depth": {b: len(q) for b, q in
@@ -1175,6 +1181,9 @@ class RouterService:
             "build": dict(self._gen.build_s),
             "policy": self._gen.tables.stats(),
         }
+        encoder = getattr(self.embedder, "telemetry", None)
+        if encoder is not None:
+            out["encoder"] = encoder()
         if self.scheduler is not None:
             out["scheduler"] = dict(self.scheduler.stats)
             out["requeue"] = {b: len(q) for b, q in

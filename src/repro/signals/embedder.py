@@ -6,26 +6,27 @@ Two backends, one interface:
   through a fixed random matrix, mean-pooled, L2-normalized.  Deterministic
   and lexically meaningful without any training — the default for the
   validator's Monte-Carlo passes, TEST blocks, and examples.
-* ``TransformerEmbedder`` — a tiny JAX transformer encoder (reuses
-  models/pattern.py blocks) over byte tokens, mean-pooled + normalized.
-  Exercises the same model substrate the backends use; can be trained
-  with train/ if desired.
+* ``EncoderEmbedder`` — a bidirectional encoder (ModernBERT, from
+  configs/encoders.py) over the models/ blocks, run on the device in
+  length buckets; its embeddings stay on the device.
 
-Both are pure-JAX after construction: ``embed(token_ids | texts)``.
+Both take ``embed(texts)`` and return (B, d) unit rows.
 """
 from __future__ import annotations
 
 import collections
-import dataclasses
-from typing import List, Sequence
+import concurrent.futures
+import functools
+import zlib
+from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ATTN, GELU_MLP, LayerSpec, ModelConfig
-from repro.models import common as cm
-from repro.models import pattern
+from repro.configs.base import ModelConfig
+from repro.models import encoder as encoder_mod
+from repro.serving.spans import PhaseCounters
 
 
 def _ngrams(text: str, lo: int = 3, hi: int = 5):
@@ -131,54 +132,180 @@ class HashEmbedder:
 
 
 # ---------------------------------------------------------------------------
-# Transformer embedder (byte-level)
+# On-device encoder embedder
 # ---------------------------------------------------------------------------
 
-def _encoder_cfg(dim: int) -> ModelConfig:
-    return ModelConfig(
-        name="query-encoder", family="dense",
-        n_layers=2, d_model=dim, n_heads=4, n_kv_heads=4, head_dim=dim // 4,
-        d_ff=dim * 4, vocab_size=256,
-        unit=(LayerSpec(mixer=ATTN, ffn=GELU_MLP, causal=False),),
-        norm="layernorm", norm_eps=1e-5, dtype="float32")
+# most tokens one full launch holds, padding included
+TOKEN_BUDGET = 32_768
+# texts embedded together: their launches write into one (GROUP_ROWS, d)
+# slab, which fixes every program's output shape
+GROUP_ROWS = 16
+MIN_BUCKET = 64
+# distinct words whose ids the tokenizer keeps; past it the memo starts over
+WORD_MEMO = 1 << 20
 
 
-class TransformerEmbedder:
-    def __init__(self, dim: int = 128, max_len: int = 64, seed: int = 0):
-        self.cfg = _encoder_cfg(dim)
-        self.max_len = max_len
-        key = jax.random.PRNGKey(seed)
-        k1, k2 = jax.random.split(key)
-        dt = jnp.float32
-        self.params = {
-            "tok_embed": cm.embed_init(k1, (256, dim), dt),
-            "stack": pattern.init_stack(k2, self.cfg),
-            "final_norm": cm.init_norm("layernorm", dim, dt),
-        }
-        self._fwd = jax.jit(self._forward)
+def _pow2(n: int) -> int:
+    return 1 << max(0, (n - 1).bit_length())
 
-    def _forward(self, params, tokens, mask):
-        x = cm.take_embedding(params["tok_embed"], tokens)
-        x = x + cm.sinusoidal_positions(tokens.shape[1], x.shape[-1],
-                                        x.dtype)[None]
-        pos = jnp.broadcast_to(
-            jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
-        x, _, _ = pattern.apply_stack(params["stack"], self.cfg, x, pos)
-        x = cm.apply_norm("layernorm", params["final_norm"], x, 1e-5)
-        m = mask[..., None].astype(x.dtype)
-        pooled = (x * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
-        return pooled / jnp.maximum(
-            jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-8)
 
-    def tokenize(self, texts: Sequence[str]) -> tuple:
-        toks = np.zeros((len(texts), self.max_len), np.int32)
-        mask = np.zeros((len(texts), self.max_len), np.bool_)
-        for i, t in enumerate(texts):
-            bs = t.encode("utf-8")[: self.max_len]
-            toks[i, : len(bs)] = np.frombuffer(bs, np.uint8)
-            mask[i, : len(bs)] = True
-        return jnp.asarray(toks), jnp.asarray(mask)
+@functools.partial(jax.jit, static_argnames=("cfg",),
+                   donate_argnames=("slab",))
+def _encode_core(params, tokens, lengths, slab, dest, *, cfg):
+    """One launch of the encoder: (R, L) right-padded token rows ->
+    their CLS embeddings, written into ``slab`` at rows ``dest``
+    (out-of-range rows, the launch's padding, are dropped)."""
+    emb = encoder_mod.encode(params, cfg, tokens, lengths)
+    return slab.at[dest].set(emb, mode="drop")
 
-    def embed(self, texts: Sequence[str]) -> np.ndarray:
-        toks, mask = self.tokenize(texts)
-        return np.asarray(self._fwd(self.params, toks, mask))
+
+class EncoderEmbedder:
+    """Embeds texts with a bidirectional encoder on the device
+    (``configs/encoders.py``); ``embed`` returns a (B, d) float32 device
+    array, which the router hands to its route program as it is.
+
+    The tokenizer stands in for the model's BPE, whose files are not in
+    the repository: whitespace words, each the CRC-32 of its UTF-8
+    bytes started from ``seed``, modulo the vocabulary less three; the
+    top three ids are [CLS], [SEP] and [PAD].  A text is
+    ``[CLS] words [SEP]``, cut to ``cfg.max_positions`` tokens.
+
+    A call's texts go in groups of ``GROUP_ROWS``; a group's texts are
+    bucketed by length (the smallest power of two at least as long, at
+    least ``MIN_BUCKET``) and each bucket is launched as rows of that
+    length (``plan``): one row, or a full launch of up to
+    ``TOKEN_BUDGET`` tokens, never a padded row.  So there are two
+    programs a bucket, and a text's numbers depend on its own length
+    alone.
+    """
+
+    def __init__(self, cfg: ModelConfig, seed: int = 0):
+        self.cfg = cfg
+        self.dim = cfg.d_model
+        self.seed = seed
+        self.max_len = cfg.max_positions
+        v = cfg.vocab_size
+        self.cls, self.sep, self.pad = v - 3, v - 2, v - 1
+        self._ids: Dict[str, int] = {}
+        self.params = jax.jit(encoder_mod.init_encoder, static_argnums=1)(
+            jax.random.PRNGKey(seed), cfg)
+        self.phases = PhaseCounters()
+        self._stats = {"calls": 0, "launches": 0, "sequences": 0,
+                       "tokens": 0, "launched_tokens": 0, "max_len": 0}
+        self._bucket_launches: Dict[int, int] = {}
+
+    # ---- tokens and buckets --------------------------------------------
+    def tokenize(self, text: str) -> List[int]:
+        ids = self._ids
+        if len(ids) > WORD_MEMO:
+            ids.clear()
+        out = [self.cls]
+        for w in text.split()[: self.max_len - 2]:
+            i = ids.get(w)
+            if i is None:
+                i = ids[w] = zlib.crc32(w.encode("utf-8"), self.seed) \
+                    % self.cls
+            out.append(i)
+        out.append(self.sep)
+        return out
+
+    def bucket(self, n_tokens: int) -> int:
+        return min(max(MIN_BUCKET, _pow2(n_tokens)), self.max_len)
+
+    def full_rows(self, length: int) -> int:
+        return min(GROUP_ROWS, max(1, TOKEN_BUDGET // length))
+
+    def plan(self, lengths: Sequence[int]
+             ) -> List[Tuple[int, int, List[int]]]:
+        """Launches for one group: (rows, bucket length, the texts'
+        indices), by length.  A bucket fills full launches of
+        ``full_rows`` and launches each text left over alone, so no row
+        is padding: a text's launched work depends on its own length,
+        whatever its batch-mates.  A launch of one row of 128 tokens or
+        more keeps the matrix units busy, and two programs a bucket keep
+        the set to compile small."""
+        by: Dict[int, List[int]] = {}
+        for i, n in enumerate(lengths):
+            by.setdefault(self.bucket(n), []).append(i)
+        out = []
+        for length in sorted(by):
+            full, idx = self.full_rows(length), by[length]
+            cut = len(idx) - len(idx) % full
+            out += [(full, length, idx[j: j + full])
+                    for j in range(0, cut, full)]
+            out += [(1, length, [i]) for i in idx[cut:]]
+        return out
+
+    def programs(self, lo: int, hi: int) -> List[Tuple[int, int]]:
+        """Every (rows, length) launch that texts of ``lo`` to ``hi``
+        tokens can make."""
+        out = []
+        length = self.bucket(lo)
+        while length <= self.bucket(hi):
+            out += sorted({(1, length), (self.full_rows(length), length)})
+            length *= 2
+        return out
+
+    def warm(self, lo: int, hi: int) -> int:
+        """Compile and run every program ``programs`` names, compiling
+        several at once; -> how many."""
+        def run(shape):
+            rows, length = shape
+            return _encode_core(
+                self.params, np.full((rows, length), self.pad, np.int32),
+                np.full(rows, length, np.int32),
+                jnp.zeros((GROUP_ROWS, self.dim), jnp.float32),
+                np.arange(rows, dtype=np.int32), cfg=self.cfg)
+        shapes = self.programs(lo, hi)
+        with concurrent.futures.ThreadPoolExecutor(8) as pool:
+            jax.block_until_ready(list(pool.map(run, shapes)))
+        return len(shapes)
+
+    # ---- embedding -------------------------------------------------------
+    def _group(self, toks: List[List[int]]):
+        slab = jnp.zeros((GROUP_ROWS, self.dim), jnp.float32)
+        st = self._stats
+        for rows, length, idx in self.plan([len(t) for t in toks]):
+            tokens = np.full((rows, length), self.pad, np.int32)
+            lens = np.ones(rows, np.int32)
+            dest = np.full(rows, GROUP_ROWS, np.int32)
+            for r, i in enumerate(idx):
+                t = toks[i]
+                tokens[r, : len(t)] = t
+                lens[r] = len(t)
+                dest[r] = i
+            slab = _encode_core(self.params, tokens, lens, slab, dest,
+                                cfg=self.cfg)
+            st["launches"] += 1
+            st["launched_tokens"] += rows * length
+            self._bucket_launches[length] = \
+                self._bucket_launches.get(length, 0) + 1
+        return slab
+
+    def embed(self, texts: Sequence[str]):
+        with self.phases.phase("router.encode", texts=len(texts)):
+            toks = [self.tokenize(t) for t in texts]
+            slabs = [self._group(toks[i: i + GROUP_ROWS])
+                     for i in range(0, len(toks), GROUP_ROWS)]
+            st = self._stats
+            st["calls"] += 1
+            st["sequences"] += len(toks)
+            st["tokens"] += sum(len(t) for t in toks)
+            st["max_len"] = max([st["max_len"]] + [len(t) for t in toks])
+            if not slabs:
+                return jnp.zeros((0, self.dim), jnp.float32)
+            out = slabs[0] if len(slabs) == 1 else jnp.concatenate(slabs)
+            return out[: len(texts)]
+
+    def telemetry(self) -> Dict[str, Any]:
+        """Cumulative counters: embed ``calls``, encoder ``launches``,
+        ``sequences``, real ``tokens``, ``launched_tokens`` (padding
+        included), ``pad_share`` of the launched tokens, the longest
+        sequence ``max_len``, ``bucket_launches`` per bucket length, and
+        the ``router.encode`` phase (n, seconds, over_100ms)."""
+        st = dict(self._stats)
+        st["pad_share"] = (1.0 - st["tokens"] / st["launched_tokens"]
+                           if st["launched_tokens"] else 0.0)
+        st["bucket_launches"] = dict(sorted(self._bucket_launches.items()))
+        st["phases"] = self.phases.snapshot()
+        return st

@@ -597,11 +597,18 @@ class SignalEngine:
         return [name.replace("_", " ")]
 
     def _bind_centroids(self):
-        for name in self.names:
+        # every prototype in one call, so an on-device embedder batches
+        # them into full launches
+        names = [n for n in self.names
+                 if self.cfg.signals[n].kind is not AtomKind.CRISP]
+        texts = [self._prototype_texts(n) for n in names]
+        flat = [t for ts in texts for t in ts]
+        emb = np.asarray(self.embedder.embed(flat)) if flat else None
+        at = 0
+        for name, ts in zip(names, texts):
             sig = self.cfg.signals[name]
-            if sig.kind is AtomKind.CRISP:
-                continue
-            protos = self.embedder.embed(self._prototype_texts(name))
+            protos = emb[at: at + len(ts)]
+            at += len(ts)
             c = protos.mean(axis=0)
             c = c / max(np.linalg.norm(c), 1e-8)
             self.centroids[name] = c.astype(np.float32)
@@ -867,7 +874,9 @@ class SignalEngine:
                 and self.kernel_mode in ("fused", "fused_dtiled"))
 
     # ---- evaluation ------------------------------------------------------------
-    def embed(self, texts: Sequence[str]) -> np.ndarray:
+    def embed(self, texts: Sequence[str]):
+        """(B, d) unit rows: numpy from a host embedder, a device array
+        from an on-device one (``EncoderEmbedder``)."""
         return self.embedder.embed(texts)
 
     def crisp_scores(self, texts: Sequence[str],
@@ -927,7 +936,7 @@ class SignalEngine:
         b = len(texts)
         n = len(self.names)
         raw = np.zeros((b, n), np.float32)
-        emb = self.embedder.embed(texts)          # (B, d)
+        emb = np.asarray(self.embedder.embed(texts))          # (B, d)
         meta = metadata or [None] * b
         for j, name in enumerate(self.names):
             sig = self.cfg.signals[name]

@@ -76,3 +76,55 @@ def test_softcap_applied():
     a = attn.attend_full(q * 10, k * 10, v, pos, pos, causal=True,
                          window=None, scale=scale, softcap=5.0)
     assert not bool(jnp.isnan(a).any())
+
+
+def _bidirectional_ref(q, k, v, kv_len, half):
+    """Softmax over keys below each row's length (and within ``half`` of
+    the query, when given), written out."""
+    b, s, h, hd = q.shape
+    g = h // k.shape[2]
+    kk, vv = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * hd ** -0.5
+    i = jnp.arange(s)
+    mask = i[None, None, None, :] < kv_len[:, None, None, None]
+    if half is not None:
+        mask &= jnp.abs(i[:, None] - i[None, :])[None, None] <= half
+    p = jax.nn.softmax(jnp.where(mask, sc, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+
+
+@pytest.mark.parametrize("s", [5, 8, 13, 64])
+def test_symmetric_band_matches_masked_softmax(s):
+    """Any length, not only multiples of the window; rows padded past
+    their length compare on their real queries."""
+    q, k, v, _ = _qkv(s=s)
+    kv_len = jnp.array([s, max(1, s - 3)], jnp.int32)
+    got = attn.attend_band(q, k, v, kv_len, window=8,
+                           scale=q.shape[-1] ** -0.5)
+    want = _bidirectional_ref(q, k, v, kv_len, 4)
+    for r, n in enumerate(np.asarray(kv_len)):
+        np.testing.assert_allclose(got[r, :n], want[r, :n], atol=2e-6)
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64])
+def test_bidirectional_chunked_matches_masked_softmax(chunk):
+    q, k, v, pos = _qkv(s=40)
+    kv_len = jnp.array([40, 11], jnp.int32)
+    got = attn.attend_chunked(q, k, v, pos, pos, causal=False, window=None,
+                              scale=q.shape[-1] ** -0.5, chunk=chunk,
+                              kv_len=kv_len)
+    want = _bidirectional_ref(q, k, v, kv_len, None)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def test_bidirectional_chunks_past_the_length_change_no_bit():
+    """A row padded into further, wholly masked key chunks keeps every
+    bit of its result."""
+    q, k, v, pos = _qkv(s=64)
+    kv_len = jnp.array([20, 20], jnp.int32)
+    one = attn.attend_chunked(q[:, :32], k[:, :32], v[:, :32], pos[:32],
+                              pos[:32], causal=False, window=None,
+                              scale=0.25, chunk=32, kv_len=kv_len)
+    two = attn.attend_chunked(q, k, v, pos, pos, causal=False, window=None,
+                              scale=0.25, chunk=32, kv_len=kv_len)
+    assert np.array_equal(np.asarray(one), np.asarray(two[:, :32]))

@@ -164,3 +164,32 @@ def test_policy_argmax_gathers_rows_at_16k_routes(one_chip, m, kp, kn):
     assert " while(" not in text and " scatter(" not in text
     # fired/conf are 1.6 MB; the two dense masks were 2.17 GB
     assert compiled.memory_analysis().argument_size_in_bytes < 2 ** 23
+
+
+def test_encoder_program_at_its_largest_bucket(one_chip):
+    """The route path's encoder (ModernBERT-base at published widths,
+    bf16) compiles for the chip as its largest launch, four rows of
+    8,192 tokens.  Global layers run the online softmax over key chunks,
+    so no (S, S) float32 score buffer of a row appears, and the
+    program's scratch stays under 2 GiB."""
+    import re
+
+    from repro.configs.encoders import MODERNBERT_BASE as cfg
+    from repro.models.encoder import init_encoder
+    from repro.signals.embedder import GROUP_ROWS, _encode_core
+    rows, length = 4, cfg.max_positions
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: init_encoder(k, cfg),
+                       jax.random.PRNGKey(0)))
+    i32 = jnp.int32
+    compiled = _encode_core.lower(
+        params, _sds(one_chip, (rows, length), i32),
+        _sds(one_chip, (rows,), i32),
+        _sds(one_chip, (GROUP_ROWS, cfg.d_model)),
+        _sds(one_chip, (rows,), i32), cfg=cfg).compile()
+    shapes = set(re.findall(r"f32\[([0-9,]+)\]", compiled.as_text()))
+    assert not [s for s in shapes
+                if s.split(",").count(str(length)) >= 2], shapes
+    assert any(str(length) in s.split(",") for s in shapes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
