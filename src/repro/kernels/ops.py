@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 
 from repro.kernels import decode_gqa as _dg
+from repro.kernels import encoder_attention as _ea
 from repro.kernels import ivf as _ivf
 from repro.kernels import ref as _ref
 from repro.kernels import voronoi as _vor
@@ -292,6 +293,15 @@ def decode_gqa(q, k, v, n_valid, *, interpret=None, use_ref=False,
     interp = _default_interpret() if interpret is None else interpret
     return _dg.decode_gqa(q, k, v, n_valid, block_s=block_s,
                           interpret=interp)
+
+
+def encoder_attention(q, k, v, kv_len, *, scale, interpret=None):
+    """Bidirectional global attention over right-padded rows: q, k, v
+    (B, S, H, hd), kv_len (B,) -> (B, S, H, hd); see
+    kernels/encoder_attention.py."""
+    interp = _default_interpret() if interpret is None else interpret
+    return _ea.encoder_attention(q, k, v, kv_len, scale=scale,
+                                 interpret=interp)
 
 
 def wkv6(r, k, v, w, u, *, interpret=None, use_ref=False, chunk: int = 64):

@@ -1,7 +1,8 @@
 """Self/cross attention: MHA, GQA, MQA; sliding windows; three prefill
 implementations (full, chunked online-softmax, banded windowed); ring-buffer
 decode caches; bidirectional (encoder) attention over right-padded rows,
-global as the chunked online softmax and windowed as a symmetric band.
+global as a flash kernel (kernels/encoder_attention.py) past one key chunk
+and otherwise the chunked online softmax, and windowed as a symmetric band.
 
 Layout conventions:
   activations  x: (B, S, D)
@@ -12,12 +13,14 @@ Keys are stored *post-RoPE* in the cache, so decode needs no re-rotation.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig
+from repro.kernels import encoder_attention as enc_attn
+from repro.kernels import ops as kops
 from repro.models import common as cm
 
 NEG_INF = -2.0e38
@@ -208,6 +211,26 @@ def attend_band(q, k, v, kv_len=None, *, window: int, scale):
     return out.reshape(b, nb * half, h, hd)[:, :s].astype(q.dtype)
 
 
+def global_kernel(cfg: ModelConfig, length: int) -> bool:
+    """Whether a bidirectional global layer over right-padded rows of
+    ``length`` runs the flash kernel: past one key chunk, where the chunk
+    scan would loop (each program that holds the kernel lowers it at
+    set-up)."""
+    return length > cfg.attn_chunk and not cfg.logit_softcap
+
+
+def global_block_pairs(cfg: ModelConfig, length: int, lens: Sequence[int]
+                       ) -> Tuple[int, int]:
+    """One bidirectional global layer over rows of ``length`` whose real
+    lengths are ``lens``: the (query block, key block) pairs the launch
+    holds, and those it computes (the kernel skips the pairs past a row's
+    length; the chunk scan computes every one)."""
+    held = len(lens) * enc_attn.blocks(length, length) ** 2
+    if not global_kernel(cfg, length):
+        return held, held
+    return held, sum(enc_attn.blocks(int(n), length) ** 2 for n in lens)
+
+
 # ---------------------------------------------------------------------------
 # Public layer application
 # ---------------------------------------------------------------------------
@@ -236,6 +259,9 @@ def self_attention(p, cfg: ModelConfig, spec: LayerSpec, x, positions,
         if not spec.causal and spec.window is not None:
             out = attend_band(q, k, v, kv_len, window=spec.window,
                               scale=scale)
+        elif (not spec.causal and kv_len is not None
+              and global_kernel(cfg, x.shape[1])):
+            out = kops.encoder_attention(q, k, v, kv_len, scale=scale)
         elif not spec.causal:
             out = attend_chunked(q, k, v, positions[0], positions[0],
                                  causal=False, window=None, scale=scale,
@@ -305,7 +331,6 @@ def _decode_attend(q, k_new, v_new, cache, pos, spec: LayerSpec,
         # semantics match both the ring buffer (n_valid) and the full
         # cache (pos+1) cases.  The kernel takes one scalar n_valid, so
         # vector-pos (slot scheduler) traffic uses the masked jnp path.
-        from repro.kernels import ops as kops
         out = kops.decode_gqa(q[:, 0], ck, cv, jnp.minimum(pos + 1, w),
                               block_s=min(512, ck.shape[1]))
         return out[:, None], {"k": ck, "v": cv}

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.models import attention
 from repro.models import encoder as encoder_mod
 from repro.serving.spans import PhaseCounters
 
@@ -191,7 +192,10 @@ class EncoderEmbedder:
             jax.random.PRNGKey(seed), cfg)
         self.phases = PhaseCounters()
         self._stats = {"calls": 0, "launches": 0, "sequences": 0,
-                       "tokens": 0, "launched_tokens": 0, "max_len": 0}
+                       "tokens": 0, "launched_tokens": 0, "max_len": 0,
+                       "global_blocks": 0, "global_blocks_run": 0}
+        self._global_layers = sum(s.window is None
+                                  for s in cfg.layer_specs())
         self._bucket_launches: Dict[int, int] = {}
 
     # ---- tokens and buckets --------------------------------------------
@@ -278,6 +282,9 @@ class EncoderEmbedder:
                                 cfg=self.cfg)
             st["launches"] += 1
             st["launched_tokens"] += rows * length
+            held, run = attention.global_block_pairs(self.cfg, length, lens)
+            st["global_blocks"] += self._global_layers * held
+            st["global_blocks_run"] += self._global_layers * run
             self._bucket_launches[length] = \
                 self._bucket_launches.get(length, 0) + 1
         return slab
@@ -301,8 +308,12 @@ class EncoderEmbedder:
         """Cumulative counters: embed ``calls``, encoder ``launches``,
         ``sequences``, real ``tokens``, ``launched_tokens`` (padding
         included), ``pad_share`` of the launched tokens, the longest
-        sequence ``max_len``, ``bucket_launches`` per bucket length, and
-        the ``router.encode`` phase (n, seconds, over_100ms)."""
+        sequence ``max_len``, ``bucket_launches`` per bucket length, the
+        global layers' (query block, key block) pairs that the launched
+        rows hold, ``global_blocks``, and that they compute,
+        ``global_blocks_run`` (``attention.global_block_pairs``), each
+        counted once a row and a global layer (heads aside), and the
+        ``router.encode`` phase (n, seconds, over_100ms)."""
         st = dict(self._stats)
         st["pad_share"] = (1.0 - st["tokens"] / st["launched_tokens"]
                            if st["launched_tokens"] else 0.0)

@@ -128,3 +128,42 @@ def test_bidirectional_chunks_past_the_length_change_no_bit():
     two = attn.attend_chunked(q, k, v, pos, pos, causal=False, window=None,
                               scale=0.25, chunk=32, kv_len=kv_len)
     assert np.array_equal(np.asarray(one), np.asarray(two[:, :32]))
+
+
+def _kernel(q, k, v, kv_len):
+    from repro.kernels import ops
+    return ops.encoder_attention(q, k, v, kv_len, scale=q.shape[-1] ** -0.5,
+                                 interpret=True)
+
+
+@pytest.mark.parametrize("s,lengths,hd", [
+    (512, [1], 64), (512, [255], 64), (512, [256], 64), (512, [257], 64),
+    (512, [512], 64),
+    (512, [1, 255, 256, 257, 512, 300], 64),  # rows of different lengths
+    (128, [128, 77], 64),                     # shorter than one block
+    (300, [300, 257], 64),                    # not a whole block
+    (256, [256, 100], 32),                    # 1/sqrt(32) scales scores
+])
+def test_global_attention_kernel_matches_masked_softmax(s, lengths, hd):
+    """The encoder's global-attention kernel (interpreted) on its real
+    positions; a query block wholly past a row's length comes out zero."""
+    q, k, v, _ = _qkv(b=len(lengths), s=s, h=4, kv=4, hd=hd, seed=s)
+    kv_len = jnp.array(lengths, jnp.int32)
+    got = np.asarray(_kernel(q, k, v, kv_len))
+    want = _bidirectional_ref(q, k, v, kv_len, None)
+    block = min(256, s)
+    for r, n in enumerate(lengths):
+        np.testing.assert_allclose(got[r, :n], want[r, :n], atol=2e-6)
+        assert not got[r, -(-n // block) * block:s].any()
+
+
+def test_global_attention_kernel_padding_changes_no_bit():
+    """A row launched at twice its bucket, its further key and query
+    blocks holding other numbers, keeps every bit of its real
+    positions."""
+    q, k, v, _ = _qkv(b=2, s=512, h=4, kv=4, hd=64, seed=3)
+    kv_len = jnp.array([200, 256], jnp.int32)
+    one = np.asarray(_kernel(q[:, :256], k[:, :256], v[:, :256], kv_len))
+    two = np.asarray(_kernel(q, k, v, kv_len))
+    for r, n in enumerate(np.asarray(kv_len)):
+        assert np.array_equal(one[r, :n], two[r, :n])

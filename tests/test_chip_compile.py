@@ -166,17 +166,32 @@ def test_policy_argmax_gathers_rows_at_16k_routes(one_chip, m, kp, kn):
     assert compiled.memory_analysis().argument_size_in_bytes < 2 ** 23
 
 
-def test_encoder_program_at_its_largest_bucket(one_chip):
+@pytest.mark.parametrize("b,s", [(1, 8192), (4, 8192), (16, 1024)])
+def test_encoder_attention_kernel(one_chip, b, s):
+    """The encoder's global-attention kernel at 12 heads of 64 (bf16), at
+    the route path's largest launches, fits the chip's scoped VMEM."""
+    from repro.kernels import encoder_attention as ea
+    x = _sds(one_chip, (b, s, 12, 64), jnp.bfloat16)
+    _assert_kernel(ea.encoder_attention.lower(
+        x, x, x, _sds(one_chip, (b,), jnp.int32), scale=0.125,
+        interpret=False).compile())
+
+
+def test_encoder_program_at_its_largest_bucket(one_chip, monkeypatch):
     """The route path's encoder (ModernBERT-base at published widths,
     bf16) compiles for the chip as its largest launch, four rows of
-    8,192 tokens.  Global layers run the online softmax over key chunks,
-    so no (S, S) float32 score buffer of a row appears, and the
+    8,192 tokens.  Global layers run the attention kernel, in the unit
+    scan and in layer 0, so no key-chunk scan is left beside the unit
+    scan, no (S, S) float32 score buffer of a row appears, and the
     program's scratch stays under 2 GiB."""
     import re
 
     from repro.configs.encoders import MODERNBERT_BASE as cfg
     from repro.models.encoder import init_encoder
     from repro.signals.embedder import GROUP_ROWS, _encode_core
+    # the described chip is not the default backend, which would pick
+    # the kernels' interpreter
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
     rows, length = 4, cfg.max_positions
     params = jax.tree.map(
         lambda a: _sds(one_chip, a.shape, a.dtype),
@@ -188,7 +203,10 @@ def test_encoder_program_at_its_largest_bucket(one_chip):
         _sds(one_chip, (rows,), i32),
         _sds(one_chip, (GROUP_ROWS, cfg.d_model)),
         _sds(one_chip, (rows,), i32), cfg=cfg).compile()
-    shapes = set(re.findall(r"f32\[([0-9,]+)\]", compiled.as_text()))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert text.count(" while(") == 1
+    shapes = set(re.findall(r"f32\[([0-9,]+)\]", text))
     assert not [s for s in shapes
                 if s.split(",").count(str(length)) >= 2], shapes
     assert any(str(length) in s.split(",") for s in shapes)

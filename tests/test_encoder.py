@@ -10,6 +10,7 @@ import pytest
 
 import encoder_ref as ref
 from repro.configs.encoders import MODERNBERT_BASE, modernbert
+from repro.kernels import ops as kops
 from repro.models.encoder import encode, init_encoder
 from repro.serving import router as router_mod
 from repro.serving.router import RouterService
@@ -67,6 +68,24 @@ def test_embedding_matches_reference(ref_weights, n):
     want = _reference(ref_weights, tokens)
     assert np.abs(got - want).max() < TOL
     assert abs(np.linalg.norm(got) - 1.0) < 1e-6
+
+
+@pytest.mark.parametrize("chunk,kernel", [(16, True), (64, False)])
+def test_global_layers_take_the_kernel_past_one_key_chunk(
+        ref_weights, monkeypatch, chunk, kernel):
+    """A 64-position launch runs the global layers' attention kernel when
+    it is longer than a key chunk and the chunk scan otherwise; both
+    match the reference."""
+    calls, real = [], kops.encoder_attention
+
+    def counted(*a, **k):
+        calls.append(a[0].shape)
+        return real(*a, **k)
+    monkeypatch.setattr(kops, "encoder_attention", counted)
+    tokens = np.random.default_rng(37).integers(0, 509, size=37)
+    got = _program(small_modernbert(attn_chunk=chunk), tokens)
+    assert bool(calls) == kernel
+    assert np.abs(got - _reference(ref_weights, tokens)).max() < TOL
 
 
 def test_bf16_activations_fall_outside_the_tolerance():
@@ -218,6 +237,24 @@ def test_encoder_telemetry_counts_real_and_padded_tokens(embedder):
     enc = after["phases"]["router.encode"]
     assert enc["n"] == before["phases"]["router.encode"]["n"] + 2
     assert "router.encode" not in svc.telemetry()["route"]["phases"]
+
+
+@pytest.mark.parametrize("chunk,run", [(16, 1 + 4 + 9), (1024, 1 + 4 + 16)])
+def test_global_block_counters_match_a_hand_count(chunk, run):
+    """Bucket 1,024 holds 4 x 4 blocks of 256 and a 602-token row's 3 x 3
+    real ones; bucket 512, 2 x 2 of 2 x 2; bucket 64 is one block.  The
+    kernel computes the real ones; at a key chunk of 1,024 no launch runs
+    it and the chunk scan computes every block.  Two global layers count
+    each."""
+    emb = EncoderEmbedder(small_modernbert(max_position_embeddings=1024,
+                                           attn_chunk=chunk), seed=SEED)
+    rng = np.random.default_rng(5)
+    emb.embed([_text(rng, w) for w in (600, 10, 300)])
+    st = emb.telemetry()
+    assert [(r, n) for r, n, _ in emb.plan([602, 12, 302])] == \
+        [(1, 64), (1, 512), (1, 1024)]
+    assert st["global_blocks"] == 2 * (1 + 4 + 16)
+    assert st["global_blocks_run"] == 2 * run
 
 
 def test_published_config():
